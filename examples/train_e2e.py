@@ -6,8 +6,10 @@ and verifies the run resumes bit-identically from the last checkpoint.
 
 CPU demo (reduced model, ~2 min):
     PYTHONPATH=src python examples/train_e2e.py
-Larger (~100M params — slow on CPU, sized for a real accelerator):
+Larger (~100M params, float32 — slow on CPU):
     PYTHONPATH=src python examples/train_e2e.py --big --steps 300
+The same A/B check at full internlm2-1.8b width on one TPU chip is
+``chip_smoke.py`` at the repository root.
 """
 import argparse
 import shutil

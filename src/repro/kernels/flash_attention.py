@@ -7,6 +7,11 @@ score tensors). Supports causal + sliding-window masks and tanh soft-capping
 (gemma2). GQA is handled by the caller (kv expanded to q heads — the repeat
 is free inside the kernel index_map: kv head index = h // group).
 
+Layout: ``[B, S, H, D]`` is viewed (free reshape) as ``[B, S, H*D]`` and a
+head is the column block ``h`` of width D, so every block's last two dims
+are ``(block, D)``: Mosaic's (8, 128) tiling rule holds for D a multiple of
+128 without transposing the inputs.
+
 Validated on CPU via ``interpret=True`` against ``ref.flash_attention_ref``.
 """
 from __future__ import annotations
@@ -18,6 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -35,9 +41,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # [bq, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # [bk, D]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)                 # [bq, D]
+    k = k_ref[...].astype(jnp.float32)                 # [bk, D]
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -54,30 +60,28 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             mask &= k_pos > q_pos - window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[:, 0]                               # [bq]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev = m_scr[...]                                # [bq, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(mask, p, 0.0)
-    l_new = alpha * l_scr[:, 0] + jnp.sum(p, axis=1)
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
     pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
-    m_scr[...] = m_new[:, None]
-    l_scr[...] = l_new[:, None]
+    acc_scr[...] = acc_scr[...] * alpha + pv
+    m_scr[...] = m_new
 
     @pl.when(ik == n_k - 1)
     def _finish():
-        l = l_scr[:, 0]
+        l = l_scr[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_scr[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / safe).astype(o_ref.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q,k,v: [B, S, H, D] (H = q heads; kv pre-expanded). -> [B, S, H, D]."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
@@ -90,30 +94,23 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kern = functools.partial(
         _kernel, scale=1.0 / math.sqrt(D), causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_k=block_k, n_k=n_k)
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, block_q, D), lambda b, h, iq, ik: (b, iq, h))
+    kv_spec = pl.BlockSpec((None, block_k, D), lambda b, h, iq, ik: (b, ik, h))
+    out = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Sq, H * D), q.dtype),
         scratch_shapes=[
-            _vmem((block_q, 1), jnp.float32),   # running max  m
-            _vmem((block_q, 1), jnp.float32),   # running sum  l
-            _vmem((block_q, D), jnp.float32),   # accumulator
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running max  m
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running sum  l
+            pltpu.VMEM((block_q, D), jnp.float32),   # accumulator
         ],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "parallel", "arbitrary"))
-        ) if not interpret else None,
-    )(q, k, v)
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+    )(q.reshape(B, Sq, H * D), k.reshape(B, Sk, H * D),
+      v.reshape(B, Sk, H * D))
+    return out.reshape(B, Sq, H, D)

@@ -16,6 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(a_ref, b_ref, o_ref, h_scr, *, chunk: int):
@@ -35,7 +36,7 @@ def _kernel(a_ref, b_ref, o_ref, h_scr, *, chunk: int):
 
 
 def selective_scan(a: jax.Array, b: jax.Array, *, chunk: int = 256,
-                   block_f: int = 1024, interpret: bool = True) -> jax.Array:
+                   block_f: int = 1024, interpret: bool) -> jax.Array:
     """a, b: [B, S, DI, DS] f32 -> h [B, S, DI, DS] (see ref.py oracle)."""
     B, S, DI, DS = a.shape
     F = DI * DS
@@ -56,16 +57,9 @@ def selective_scan(a: jax.Array, b: jax.Array, *, chunk: int = 256,
         out_specs=pl.BlockSpec((1, chunk, block_f),
                                lambda b_, jf, ic: (b_, ic, jf)),
         out_shape=jax.ShapeDtypeStruct((B, S, F), a.dtype),
-        scratch_shapes=[_vmem((1, block_f), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_f), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(af, bf)
     return out.reshape(B, S, DI, DS)
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)

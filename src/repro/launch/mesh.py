@@ -20,11 +20,7 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {n} devices but only {len(devs)} present — "
             "run via launch/dryrun.py which forces 512 host devices")
-    try:
-        return jax.make_mesh(shape, axes, devices=devs[:n])
-    except TypeError:   # older jax without devices kwarg
-        from jax.sharding import Mesh
-        return Mesh(np.asarray(devs[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devs[:n])
 
 
 def make_local_mesh(axes=("data", "model")):

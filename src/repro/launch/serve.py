@@ -1,11 +1,11 @@
 """Serving driver: batched decode over any assigned architecture.
 
-CPU demo (reduced config):
+Reduced configs only, on one device (CPU or one chip):
     PYTHONPATH=src python -m repro.launch.serve --arch gemma2-9b \
         --requests 8 --tokens 16
-On TPU the same ``serve_step`` is what the decode_32k / long_500k dry-run
-cells lower for the production mesh (params TP/FSDP-sharded, KV caches
-sequence-sharded — see launch/dryrun.py).
+No full-width serving path exists yet. ``serve_step`` is also what
+``launch/dryrun.py`` lowers, on forced CPU devices, for the production
+mesh's decode cells; nothing has run it sharded on a chip.
 """
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.serving import SlotServer
 
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--d-model", type=int, default=128)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     full = get_config(args.arch)
     cfg = reduced(full, d_model=args.d_model,

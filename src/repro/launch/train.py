@@ -1,10 +1,13 @@
-"""End-to-end training driver: LOG.io-protected data pipeline + SPMD train
-step + checkable checkpoint write actions.
+"""End-to-end training driver: LOG.io-protected data pipeline + train step
++ checkable checkpoint write actions, on one device.
 
-CPU (this container): reduced configs, local 1-device mesh —
+CPU (reduced width, float32 — ``presets.REDUCED_TRAIN``):
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
         --steps 60 --kill-worker-at 15 --kill-trainer-at 30
-TPU: pass --full; the same driver shards via the production mesh rules.
+One TPU v5e chip (full published width — ``presets.ONE_CHIP_TRAIN``: bf16
+params, int8 AdamW moments, bf16 accumulation, full remat, 2 x 4096 tokens):
+    PYTHONPATH=src python -m repro.launch.train --full --steps 8 --ckpt-every 4
+No mesh is built: the step runs unsharded on the default device.
 
 Exactly-once training semantics: consumed batches are acknowledged (their
 Input Sets marked done, with the checkpoint as the covering *write action*)
@@ -21,6 +24,7 @@ from __future__ import annotations
 
 import argparse
 import queue as _queue
+import tempfile
 import time
 from typing import Optional
 
@@ -31,26 +35,78 @@ from repro.checkpoint import CheckpointStore
 from repro.configs import get_config, reduced
 from repro.core.engine import Engine, FailureInjector
 from repro.data import build_data_pipeline
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.presets import ONE_CHIP_TRAIN, REDUCED_TRAIN
 from repro.models import model as M
 from repro.training.optimizer import OptHParams
 from repro.training.step import init_train_state, make_train_step
 
 
+def check_restorable(restored, want) -> None:
+    """Raise if a checkpoint's tree, shapes or dtypes differ from ``want``
+    (``jax.eval_shape`` of the fresh state): a stale ``ckpt_dir`` from a
+    run of another width or dtype must not be resumed."""
+    got_def, want_def = jax.tree.structure(restored), jax.tree.structure(want)
+    if got_def != want_def:
+        raise ValueError(f"checkpoint tree {got_def} does not match this "
+                         f"run's train state {want_def}")
+    for path, g, w in zip(jax.tree_util.tree_flatten_with_path(want)[0],
+                          jax.tree.leaves(restored), jax.tree.leaves(want)):
+        if (tuple(g.shape), jnp.dtype(g.dtype)) != (w.shape, w.dtype):
+            raise ValueError(
+                f"checkpoint leaf {jax.tree_util.keystr(path[0])} is "
+                f"{g.dtype}{tuple(g.shape)} but this run's train state has "
+                f"{w.dtype}{w.shape}; resume from a checkpoint of the same "
+                f"configuration or use a fresh ckpt_dir")
+
+
 def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
-                 steps: int = 60, seq_len: int = 128, batch_size: int = 4,
-                 ckpt_every: int = 10, ckpt_dir: str = "/tmp/repro_ckpt",
+                 steps: int = 60, seq_len: Optional[int] = None,
+                 batch_size: Optional[int] = None,
+                 ckpt_every: int = 10, ckpt_dir: Optional[str] = None,
                  kill_worker_at: Optional[int] = None,
                  kill_trainer_at: Optional[int] = None,
                  lr: float = 1e-3, seed: int = 0, log_every: int = 10,
                  d_model: int = 256, n_layers: int = 4, verbose: bool = True):
+    """``ckpt_dir`` defaults to a fresh temporary directory; pass an existing
+    one to resume from its latest checkpoint."""
+    ts = REDUCED_TRAIN if use_reduced else ONE_CHIP_TRAIN
+    seq_len = seq_len or ts.seq_len
+    batch_size = batch_size or ts.batch_size
     cfg = get_config(arch)
     if use_reduced:
         nl = n_layers - n_layers % len(cfg.block) or len(cfg.block)
         cfg = reduced(cfg, d_model=d_model, n_layers=nl, vocab=2048,
                       d_ff=4 * d_model, n_heads=4)
-    hp = OptHParams(lr=lr, warmup=20)
-    rt = M.Runtime(remat="none", q_chunk=min(seq_len, 128),
+    hp = OptHParams(lr=lr, warmup=20, moment_dtype=ts.moment_dtype,
+                    grad_accum_dtype=ts.grad_accum_dtype)
+    rt = M.Runtime(remat=ts.remat, q_chunk=min(seq_len, ts.q_chunk),
                    shard_activations=False)
+    store = CheckpointStore(ckpt_dir or tempfile.mkdtemp(prefix="repro_ckpt_"))
+    timings = {"compile_s": None, "step_s": [], "save_s": [], "restore_s": []}
+
+    # ---- train state (restore-or-init) -----------------------------------
+    def fresh_state():
+        return init_train_state(jax.random.PRNGKey(seed), cfg, hp,
+                                dtype=jnp.dtype(ts.param_dtype))
+
+    def load_state():
+        t = time.perf_counter()
+        _, restored = store.latest()
+        if restored is None:
+            return fresh_state()
+        check_restorable(restored, jax.eval_shape(fresh_state))
+        state = jax.tree.map(jnp.asarray, restored)
+        jax.block_until_ready(state)
+        timings["restore_s"].append(time.perf_counter() - t)
+        return state
+
+    state = load_state()
+    tok_spec = jax.ShapeDtypeStruct((1, batch_size, seq_len), jnp.int32)
+    t = time.perf_counter()
+    train_step = jax.jit(make_train_step(cfg, hp, rt), donate_argnums=0).lower(
+        state, {"tokens": tok_spec, "labels": tok_spec}).compile()
+    timings["compile_s"] = time.perf_counter() - t
 
     # ---- data pipeline (LOG.io-protected) --------------------------------
     pipeline, feed_id = build_data_pipeline(
@@ -63,17 +119,6 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
         plan.append(("pack", "post_log", 2 * kill_worker_at))
     engine = Engine(pipeline, injector=FailureInjector(plan),
                     mode="thread", restart_delay=0.01)
-    store = CheckpointStore(ckpt_dir)
-
-    # ---- train state (restore-or-init) -----------------------------------
-    def fresh_state():
-        return init_train_state(jax.random.PRNGKey(seed), cfg, hp,
-                                dtype=jnp.float32)
-
-    _, restored = store.latest()
-    state = (jax.tree.map(jnp.asarray, restored) if restored is not None
-             else fresh_state())
-    train_step = jax.jit(make_train_step(cfg, hp, rt))
 
     def next_batch(deadline=30.0):
         t_end = time.time() + deadline
@@ -96,13 +141,18 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
         toks = jnp.asarray(body["tokens"][:batch_size])
         batch = {"tokens": toks[None, :, :-1],
                  "labels": toks[None, :, 1:].astype(jnp.int32)}
+        t = time.perf_counter()
         state, metrics = train_step(state, batch)
+        jax.block_until_ready(state)
+        timings["step_s"].append(time.perf_counter() - t)
         step = int(state["step"])
         losses.append(float(metrics["loss"]))
         pending_insets.append(inset)
 
         if step % ckpt_every == 0 or step >= steps:
+            t = time.perf_counter()
             ref = store.save(state, step)
+            timings["save_s"].append(time.perf_counter() - t)
             feed_now = engine.ops[feed_id]
             for ins in pending_insets:
                 feed_now.complete(ins, step, ref)
@@ -122,9 +172,8 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
                       f"restoring from checkpoint", flush=True)
             old_feed = engine.ops[feed_id]
             engine.kill_group(engine.pipeline.groups[feed_id])
-            _, restored = store.latest()
-            state = (jax.tree.map(jnp.asarray, restored)
-                     if restored is not None else fresh_state())
+            state = metrics = None    # free the device copy before reloading
+            state = load_state()
             pending_insets = []
             # wait for the feed group to be rebuilt (fresh buffer)
             t_end = time.time() + 10
@@ -134,7 +183,7 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
     engine.stop()
     return {"losses": losses, "crash_steps": crash_steps, "engine": engine,
             "final_state": state, "store": store,
-            "steps": int(state["step"])}
+            "steps": int(state["step"]), "timings": timings}
 
 
 def main():
@@ -143,16 +192,19 @@ def main():
     ap.add_argument("--full", dest="reduced", action="store_false",
                     default=True)
     ap.add_argument("--steps", type=int, default=60)
-    ap.add_argument("--seq-len", type=int, default=128)
-    ap.add_argument("--batch-size", type=int, default=4)
+    ap.add_argument("--seq-len", type=int, default=None)
+    ap.add_argument("--batch-size", type=int, default=None)
     ap.add_argument("--d-model", type=int, default=256)
     ap.add_argument("--n-layers", type=int, default=4)
     ap.add_argument("--ckpt-every", type=int, default=10)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="resume from / save into this directory "
+                         "(default: a fresh temporary directory)")
     ap.add_argument("--kill-worker-at", type=int, default=None)
     ap.add_argument("--kill-trainer-at", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
     out = run_training(arch=args.arch, use_reduced=args.reduced,
                        steps=args.steps, seq_len=args.seq_len,
                        batch_size=args.batch_size, ckpt_every=args.ckpt_every,
@@ -163,7 +215,8 @@ def main():
                        seed=args.seed)
     print(f"finished at step {out['steps']}; "
           f"pipeline failures={out['engine'].failures} "
-          f"restarts={out['engine'].restarts}")
+          f"restarts={out['engine'].restarts}; "
+          f"checkpoints in {out['store'].dir}")
 
 
 if __name__ == "__main__":

@@ -69,7 +69,7 @@ def train_step(state: Dict[str, Any], batch: Dict[str, jax.Array], *,
 
 
 def make_train_step(cfg, hp: OptHParams, rt: M.Runtime,
-                    compress_grads: bool = False, donate: bool = True):
+                    compress_grads: bool = False):
     fn = functools.partial(train_step, cfg=cfg, hp=hp, rt=rt,
                            compress_grads=compress_grads)
     return fn

@@ -100,3 +100,11 @@ def test_model_attention_path_uses_kernel_consistently():
                                    attn_impl="pallas")
     np.testing.assert_allclose(np.asarray(out_xla), np.asarray(out_pallas),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_kernels_interpret_on_cpu_backend_only(monkeypatch):
+    """The backend, not a switch, decides interpret mode."""
+    import jax
+    assert ops.interpret_mode() == (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.interpret_mode() is False

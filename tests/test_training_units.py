@@ -97,3 +97,26 @@ def test_grad_compression_roundtrip_small_error():
         np.abs(np.asarray(w1)).max() + 1e-9)
     assert rel < 0.02
     assert np.isfinite(float(m2["loss"]))
+
+
+def test_stale_checkpoint_of_another_width_is_refused(tmp_path):
+    """A ckpt_dir left by a run of another width must not be resumed."""
+    from repro.launch.train import run_training
+    d = str(tmp_path / "ckpt")
+    out = run_training(steps=2, ckpt_every=2, seq_len=16, batch_size=2,
+                       ckpt_dir=d, d_model=32, n_layers=1, verbose=False)
+    assert out["store"].latest()[0] == 2
+    with pytest.raises(ValueError, match="checkpoint leaf"):
+        run_training(steps=4, ckpt_every=2, seq_len=16, batch_size=2,
+                     ckpt_dir=d, d_model=64, n_layers=1, verbose=False)
+
+
+def test_checkpoint_of_the_same_configuration_resumes(tmp_path):
+    from repro.launch.train import run_training
+    d = str(tmp_path / "ckpt")
+    kw = dict(ckpt_every=2, seq_len=16, batch_size=2, ckpt_dir=d,
+              d_model=32, n_layers=1, verbose=False)
+    run_training(steps=2, **kw)
+    out = run_training(steps=4, **kw)
+    assert len(out["losses"]) == 2 and out["steps"] == 4
+    assert len(out["timings"]["restore_s"]) == 1
