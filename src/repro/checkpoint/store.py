@@ -21,6 +21,8 @@ from typing import Any, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.core.metrics import span
+
 
 class CheckpointStore:
     def __init__(self, directory: str):
@@ -33,16 +35,22 @@ class CheckpointStore:
 
     def save(self, state: Any, step: int) -> str:
         """Durable write: temp file + atomic rename (the 'success response'
-        of Sec. 2.2 — once renamed, the write is durable)."""
-        host_state = jax.tree.map(np.asarray, state)
+        of Sec. 2.2 — once renamed, the write is durable). Spans: the copy
+        to the host (``ckpt.pull``), the pickle (``ckpt.write``), the fsync
+        and rename (``ckpt.fsync``)."""
+        with span("ckpt.pull"):
+            host_state = jax.tree.map(np.asarray, state)
         path = self._path(step)
         with self.lock:
             fd, tmp = tempfile.mkstemp(dir=self.dir)
             with os.fdopen(fd, "wb") as f:
-                pickle.dump({"step": step, "state": host_state}, f)
-                f.flush()
-                os.fsync(f.fileno())
-            os.replace(tmp, path)
+                with span("ckpt.write"):
+                    pickle.dump({"step": step, "state": host_state}, f)
+                    f.flush()
+                with span("ckpt.fsync"):
+                    os.fsync(f.fileno())
+                    f.close()
+                    os.replace(tmp, path)
         return path
 
     def status(self, step: int) -> str:

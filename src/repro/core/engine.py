@@ -31,7 +31,6 @@ import os
 import pickle
 import threading
 import time
-import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.batching import make_governor, resolve_batching
@@ -760,47 +759,6 @@ class Engine:
                               op_counters=op_counters, groups=groups,
                               queue_depths=qdepth, wire=wire,
                               store=self.store, recovery_modes=modes)
-
-    # -- deprecated accessors (shims over metrics()) --------------------
-    #: the legacy ``op_stats_detail`` dict keys (rt.stats shape)
-    _DETAIL_KEYS = ("events_in", "events_out", "txns", "recovered_resends",
-                    "recovered_inputs", "recovery_scan_batches",
-                    "batched_runs", "batched_events", "commit_us",
-                    "send_stall_us")
-
-    def process_stats(self) -> Dict[str, int]:
-        """Deprecated: use ``Engine.metrics()`` (``ops[op].processed``)."""
-        warnings.warn(
-            "Engine.process_stats() is deprecated; use Engine.metrics() — "
-            "MetricsSnapshot.ops[op].processed", DeprecationWarning,
-            stacklevel=2)
-        return {op: m.processed for op, m in self.metrics().ops.items()}
-
-    def op_stats_detail(self) -> Dict[str, Dict[str, int]]:
-        """Deprecated: use ``Engine.metrics()`` (``ops[op]`` fields)."""
-        warnings.warn(
-            "Engine.op_stats_detail() is deprecated; use Engine.metrics() "
-            "— MetricsSnapshot.ops[op] carries the same counters as typed "
-            "fields", DeprecationWarning, stacklevel=2)
-        return {op: {k: getattr(m, k) for k in self._DETAIL_KEYS}
-                for op, m in self.metrics().ops.items()}
-
-    def wire_stats(self) -> Dict[str, float]:
-        """Deprecated: use ``Engine.metrics()`` (``.transport``)."""
-        warnings.warn(
-            "Engine.wire_stats() is deprecated; use Engine.metrics() — "
-            "MetricsSnapshot.transport (TransportMetrics)",
-            DeprecationWarning, stacklevel=2)
-        t = self.metrics().transport
-        if not (t.frames or t.bytes or t.events or t.ctrl
-                or t.ctrl_frames or t.extra):
-            return {}
-        out: Dict[str, float] = {
-            "frames": t.frames, "bytes": t.bytes, "events": t.events,
-            "ctrl": t.ctrl, "ctrl_frames": t.ctrl_frames, **dict(t.extra)}
-        out["events_per_frame"] = t.events_per_frame
-        out["ctrl_per_ctrl_frame"] = t.ctrl_per_ctrl_frame
-        return out
 
     def wait(self, timeout: float = 60.0) -> bool:
         if self.protocol == "abs":

@@ -13,9 +13,13 @@ documented schema:
   * :class:`MetricsSnapshot`  — one coherent point-in-time view
 
 ``Engine.metrics()`` is the only entry point; it returns the same typed
-snapshot in thread, step and process mode.  The legacy accessors remain
-as DeprecationWarning shims (see docs/metrics.md for the field-by-field
-mapping).
+snapshot in thread, step and process mode.  Of the legacy accessors only
+``LogBackend.query_stats()`` remains, as a DeprecationWarning shim (see
+docs/metrics.md for the field-by-field mapping).
+
+:class:`span` times host work where it happens: a named interval that
+lands in the JAX profiler's trace and, on exit, in a short in-process
+record (``recent_spans``).  docs/metrics.md lists every span name.
 
 All counters are cumulative (monotone) across worker incarnations;
 gauges (``queue_depth``) are instantaneous and never folded across
@@ -24,10 +28,12 @@ incarnations.  Consumers that want rates diff two snapshots — see
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import sys
 import time
 from types import MappingProxyType
-from typing import Any, Dict, Mapping, Optional, Tuple
+from typing import Any, Deque, Dict, List, Mapping, Optional, Tuple
 
 
 def _frozen(d: Optional[Mapping]) -> Mapping:
@@ -216,3 +222,71 @@ def build_snapshot(*, mode: str, protocol: str, failures: int, restarts: int,
         transport=transport_metrics_from_wire(wire),
         store=store_metrics_from_backend(store),
         recovery_modes=_frozen(recovery_modes))
+
+
+# ---------------------------------------------------------------------------
+# spans: named intervals of host work
+# ---------------------------------------------------------------------------
+
+#: finished spans kept per name for ``recent_spans``
+SPANS_KEPT = 4096
+# one record per process, as the profiler's trace is: spans end on engine
+# threads, in the checkpoint store and in the train loop, and their reader
+# (the benchmark's per-layer metrics) holds none of those objects
+_spans: Dict[str, Deque[Tuple[float, float]]] = {}
+
+
+class span:
+    """A named interval of host work: ``with span("ckpt.save"): ...``.
+
+    Entering and leaving it opens and closes a
+    ``jax.profiler.TraceAnnotation`` of the same name, with ``args`` as the
+    event's stats, so the interval lands in the profiler's trace on the
+    thread that ran it, on the clock the device planes are aligned to. When
+    no profiler is collecting, the annotation costs its enter and exit. A
+    process that has not imported JAX has no profiler to collect it, and
+    the span does not import JAX for one.
+
+    On exit the span records its length in seconds (``time.perf_counter``)
+    as ``seconds``, appends it to ``into`` when a list is given, and keeps
+    its start and end among the latest ``SPANS_KEPT`` spans of its name
+    (``recent_spans``).
+    """
+
+    __slots__ = ("name", "into", "seconds", "_note", "_t0")
+
+    def __init__(self, name: str, into: Optional[List[float]] = None,
+                 **args):
+        self.name = name
+        self.into = into
+        self.seconds = 0.0
+        profiler = sys.modules.get("jax.profiler")
+        self._note = (None if profiler is None
+                      else profiler.TraceAnnotation(name, **args))
+
+    def __enter__(self) -> "span":
+        if self._note is not None:
+            self._note.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        self.seconds = t1 - self._t0
+        kept = _spans.get(self.name)
+        if kept is None:
+            kept = _spans.setdefault(
+                self.name, collections.deque(maxlen=SPANS_KEPT))
+        kept.append((self._t0, t1))
+        if self.into is not None:
+            self.into.append(self.seconds)
+        return False
+
+
+def recent_spans(name: str) -> List[Tuple[float, float]]:
+    """``(start, end)`` on ``time.perf_counter`` of the latest finished
+    spans named ``name`` in this process, in the order they ended."""
+    kept = _spans.get(name)
+    return [] if kept is None else list(kept.copy())

@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tupl
 
 from repro.core.events import DONE, REPLAY, UNDONE, Event, ReadAction
 from repro.core.logstore import LogBackend, TxnAborted
+from repro.core.metrics import span
 
 
 class SimulatedCrash(Exception):
@@ -316,13 +317,15 @@ class OperatorRuntime:
                     self.ctx.last_acked[port], last)
 
     def _commit(self, txn):
-        """Commit with latency accounting (``commit_us`` feeds the
-        adaptive controller's commit-share signal)."""
-        t0 = time.perf_counter()
+        """Commit with latency accounting: one ``log.commit`` span, whose
+        length feeds ``commit_us`` (the adaptive controller's commit-share
+        signal)."""
+        commit = span("log.commit", op=self.op.id)
         try:
-            return txn.commit()
+            with commit:
+                return txn.commit()
         finally:
-            self.stats["commit_us"] += int((time.perf_counter() - t0) * 1e6)
+            self.stats["commit_us"] += int(commit.seconds * 1e6)
 
     # ---- normal processing: one input event (Algorithm 2) ----------------
     def handle_input(self, port: str, ev: Event) -> bool:

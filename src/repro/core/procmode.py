@@ -641,14 +641,10 @@ class ProcessEngineDriver:
         self._hub: Optional[_ControlHub] = None
         self._nodes: Dict[str, _NodeHandle] = {}
         self._nodes_cv = threading.Condition()
-        # cumulative per-op event counters across worker incarnations
-        # (live worker stats land in _op_stats_live, folded into
-        # _op_stats_base when the incarnation dies)
-        self._op_stats_base: Dict[str, Dict[str, int]] = {}
-        self._op_stats_live: Dict[str, Dict[str, int]] = {}
-        # full per-operator counter dicts (txns, batched_runs,
-        # recovery_scan_batches, ...), same base/live split — op_stats()
-        # keeps its collapsed events_in+events_out shape for the benches
+        # per-operator counter dicts (txns, batched_runs,
+        # recovery_scan_batches, ...) across worker incarnations: a live
+        # worker's land in _op_detail_live, folded into _op_detail_base
+        # when the incarnation dies
         self._op_detail_base: Dict[str, Dict[str, Dict[str, int]]] = {}
         self._op_detail_live: Dict[str, Dict[str, Dict[str, int]]] = {}
         # wire-level transport counters (superframes/bytes/coalescing),
@@ -684,9 +680,6 @@ class ProcessEngineDriver:
             g = gauges[op] = {}
             for k, n in s.items():
                 (g if k.startswith("g_") else c)[k] = n
-        self._op_stats_live[group] = {
-            op: s.get("events_in", 0) + s.get("events_out", 0)
-            for op, s in counters.items()}
         self._op_detail_live[group] = counters
         self._op_gauge_live[group] = gauges
 
@@ -1012,9 +1005,6 @@ class ProcessEngineDriver:
     def _fold_stats_locked(self, group: str) -> None:
         """An incarnation died/stopped: fold its live counters into the
         cumulative base (driver lock held)."""
-        base = self._op_stats_base.setdefault(group, {})
-        for op, n in self._op_stats_live.pop(group, {}).items():
-            base[op] = base.get(op, 0) + n
         dbase = self._op_detail_base.setdefault(group, {})
         for op, s in self._op_detail_live.pop(group, {}).items():
             acc = dbase.setdefault(op, {})
@@ -1027,50 +1017,6 @@ class ProcessEngineDriver:
         wbase = self._wire_base.setdefault(group, {})
         for k, n in self._wire_live.pop(group, {}).items():
             wbase[k] = wbase.get(k, 0) + n
-
-    def op_stats(self) -> Dict[str, int]:
-        """Cumulative processed-event counters per operator across worker
-        incarnations (benchmark instrumentation)."""
-        with self.lock:
-            out: Dict[str, int] = {}
-            for g, ops in self._op_stats_base.items():
-                for op, n in ops.items():
-                    out[op] = out.get(op, 0) + n
-            for g, ops in self._op_stats_live.items():
-                for op, n in ops.items():
-                    out[op] = out.get(op, 0) + n
-            return out
-
-    def op_stats_detail(self) -> Dict[str, Dict[str, int]]:
-        """Full per-operator counter dicts (txns, batched_runs/_events,
-        recovery_scan_batches, ...) summed across incarnations."""
-        with self.lock:
-            out: Dict[str, Dict[str, int]] = {}
-            for src in (self._op_detail_base, self._op_detail_live):
-                for g, ops in src.items():
-                    for op, s in ops.items():
-                        acc = out.setdefault(op, {})
-                        for k, n in s.items():
-                            acc[k] = acc.get(k, 0) + n
-            return out
-
-    def wire_stats(self) -> Dict[str, float]:
-        """Cumulative wire-protocol counters across all workers and
-        incarnations (byte transports only; empty under ``routed``):
-        superframes, bytes, events and control entries carried, plus the
-        derived coalescing ratios the benchmarks report."""
-        with self.lock:
-            out: Dict[str, float] = {}
-            for src in (self._wire_base, self._wire_live):
-                for g, w in src.items():
-                    for k, n in w.items():
-                        out[k] = out.get(k, 0) + n
-            if out.get("frames"):
-                out["events_per_frame"] = out.get("events", 0) / out["frames"]
-            if out.get("ctrl_frames"):
-                out["ctrl_per_ctrl_frame"] = (out.get("ctrl", 0)
-                                              / out["ctrl_frames"])
-            return out
 
     def metrics_raw(self):
         """Raw material for ``Engine.metrics()``: per-op counter dicts

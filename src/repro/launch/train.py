@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.checkpoint import CheckpointStore
 from repro.configs import get_config, reduced
 from repro.core.engine import Engine, FailureInjector
+from repro.core.metrics import span
 from repro.data import build_data_pipeline
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.presets import ONE_CHIP_TRAIN, REDUCED_TRAIN
@@ -69,7 +70,15 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
                  lr: float = 1e-3, seed: int = 0, log_every: int = 10,
                  d_model: int = 256, n_layers: int = 4, verbose: bool = True):
     """``ckpt_dir`` defaults to a fresh temporary directory; pass an existing
-    one to resume from its latest checkpoint."""
+    one to resume from its latest checkpoint.
+
+    Each iteration of the loop is a ``jax.profiler.StepTraceAnnotation``
+    ("train", ``step_num`` the step it trains) tiled by the spans
+    ``feed.get``, ``feed.put``, ``step.run``, ``step.sync`` and, at a
+    checkpoint, ``ckpt.save`` and ``feed.ack`` (``core/metrics.span``;
+    docs/metrics.md). ``timings`` holds the lengths of ``step.compile``
+    (``compile_s``), ``step.run`` (``step_s``), ``ckpt.save`` (``save_s``)
+    and of each ``ckpt.restore`` that found a checkpoint (``restore_s``)."""
     ts = REDUCED_TRAIN if use_reduced else ONE_CHIP_TRAIN
     seq_len = seq_len or ts.seq_len
     batch_size = batch_size or ts.batch_size
@@ -91,22 +100,24 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
                                 dtype=jnp.dtype(ts.param_dtype))
 
     def load_state():
-        t = time.perf_counter()
-        _, restored = store.latest()
+        with span("ckpt.restore") as restore:
+            _, restored = store.latest()
+            if restored is not None:
+                check_restorable(restored, jax.eval_shape(fresh_state))
+                state = jax.tree.map(jnp.asarray, restored)
+                jax.block_until_ready(state)
         if restored is None:
             return fresh_state()
-        check_restorable(restored, jax.eval_shape(fresh_state))
-        state = jax.tree.map(jnp.asarray, restored)
-        jax.block_until_ready(state)
-        timings["restore_s"].append(time.perf_counter() - t)
+        timings["restore_s"].append(restore.seconds)
         return state
 
     state = load_state()
     tok_spec = jax.ShapeDtypeStruct((1, batch_size, seq_len), jnp.int32)
-    t = time.perf_counter()
-    train_step = jax.jit(make_train_step(cfg, hp, rt), donate_argnums=0).lower(
-        state, {"tokens": tok_spec, "labels": tok_spec}).compile()
-    timings["compile_s"] = time.perf_counter() - t
+    with span("step.compile") as compile_:
+        train_step = jax.jit(make_train_step(cfg, hp, rt),
+                             donate_argnums=0).lower(
+            state, {"tokens": tok_spec, "labels": tok_spec}).compile()
+    timings["compile_s"] = compile_.seconds
 
     # ---- data pipeline (LOG.io-protected) --------------------------------
     pipeline, feed_id = build_data_pipeline(
@@ -121,8 +132,8 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
                     mode="thread", restart_delay=0.01)
 
     def next_batch(deadline=30.0):
-        t_end = time.time() + deadline
-        while time.time() < t_end:
+        t_end = time.monotonic() + deadline
+        while time.monotonic() < t_end:
             feed = engine.ops[feed_id]
             feed.requeue()
             try:
@@ -135,52 +146,60 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
     losses, crash_steps = [], []
     pending_insets = []
     killed_trainer = False
-    t0 = time.time()
-    while int(state["step"]) < steps:
-        feed, (inset, body) = next_batch()
-        toks = jnp.asarray(body["tokens"][:batch_size])
-        batch = {"tokens": toks[None, :, :-1],
-                 "labels": toks[None, :, 1:].astype(jnp.int32)}
-        t = time.perf_counter()
-        state, metrics = train_step(state, batch)
-        jax.block_until_ready(state)
-        timings["step_s"].append(time.perf_counter() - t)
-        step = int(state["step"])
-        losses.append(float(metrics["loss"]))
-        pending_insets.append(inset)
+    t0 = time.monotonic()
+    step = int(state["step"])
+    while step < steps:
+        with jax.profiler.StepTraceAnnotation("train", step_num=step + 1):
+            with span("feed.get"):
+                feed, (inset, body) = next_batch()
+            with span("feed.put"):
+                toks = jnp.asarray(body["tokens"][:batch_size])
+                batch = {"tokens": toks[None, :, :-1],
+                         "labels": toks[None, :, 1:].astype(jnp.int32)}
+            with span("step.run", into=timings["step_s"]):
+                state, metrics = train_step(state, batch)
+                jax.block_until_ready(state)
+            with span("step.sync"):
+                step = int(state["step"])
+                loss = float(metrics["loss"])
+                losses.append(loss)
+                pending_insets.append(inset)
+                if verbose and (step % log_every == 0 or step >= steps):
+                    print(f"step {step:4d} loss {loss:.4f} "
+                          f"gnorm {float(metrics['grad_norm']):.3f} "
+                          f"({time.monotonic() - t0:.1f}s)", flush=True)
 
-        if step % ckpt_every == 0 or step >= steps:
-            t = time.perf_counter()
-            ref = store.save(state, step)
-            timings["save_s"].append(time.perf_counter() - t)
-            feed_now = engine.ops[feed_id]
-            for ins in pending_insets:
-                feed_now.complete(ins, step, ref)
-            pending_insets = []
+            if step % ckpt_every == 0 or step >= steps:
+                with span("ckpt.save", into=timings["save_s"]):
+                    ref = store.save(state, step)
+                with span("feed.ack"):
+                    feed_now = engine.ops[feed_id]
+                    for ins in pending_insets:
+                        feed_now.complete(ins, step, ref)
+                    pending_insets = []
 
-        if verbose and (step % log_every == 0 or step >= steps):
-            print(f"step {step:4d} loss {metrics['loss']:.4f} "
-                  f"gnorm {metrics['grad_norm']:.3f} "
-                  f"({time.time()-t0:.1f}s)", flush=True)
+            if (kill_trainer_at is not None and step >= kill_trainer_at
+                    and not killed_trainer):
+                with span("trainer.restart"):
+                    killed_trainer = True
+                    crash_steps.append(step)
+                    if verbose:
+                        print(f"!! trainer crash at step {step}: dropping "
+                              f"state, restoring from checkpoint", flush=True)
+                    old_feed = engine.ops[feed_id]
+                    engine.kill_group(engine.pipeline.groups[feed_id])
+                    state = metrics = None  # free the device copy first
+                    state = load_state()
+                    step = int(state["step"])
+                    pending_insets = []
+                    # wait for the feed group to be rebuilt (fresh buffer)
+                    t_end = time.monotonic() + 10
+                    while (engine.ops[feed_id] is old_feed
+                           and time.monotonic() < t_end):
+                        time.sleep(0.01)
 
-        if (kill_trainer_at is not None and step >= kill_trainer_at
-                and not killed_trainer):
-            killed_trainer = True
-            crash_steps.append(step)
-            if verbose:
-                print(f"!! trainer crash at step {step}: dropping state, "
-                      f"restoring from checkpoint", flush=True)
-            old_feed = engine.ops[feed_id]
-            engine.kill_group(engine.pipeline.groups[feed_id])
-            state = metrics = None    # free the device copy before reloading
-            state = load_state()
-            pending_insets = []
-            # wait for the feed group to be rebuilt (fresh buffer)
-            t_end = time.time() + 10
-            while engine.ops[feed_id] is old_feed and time.time() < t_end:
-                time.sleep(0.01)
-
-    engine.stop()
+    with span("feed.stop"):
+        engine.stop()
     return {"losses": losses, "crash_steps": crash_steps, "engine": engine,
             "final_state": state, "store": store,
             "steps": int(state["step"]), "timings": timings}

@@ -149,29 +149,36 @@ def _apply_layer(p: Params, spec: LayerSpec, x, positions, cfg, rt: Runtime,
                  memory=None, mem_positions=None):
     aux = jnp.zeros((), jnp.float32)
     x = L._cs(x, "dp", None, None)
-    h = L.rms_norm(x, p["norm1"], cfg.norm_eps)
-    if spec.mixer == "attn":
-        mix = L.apply_attention(p["attn"], h, spec.attn, cfg, positions,
-                                q_chunk=rt.q_chunk, attn_impl=rt.attn_impl)
-    else:
-        mix = L.apply_mamba(p["mamba"], h, cfg, scan_impl=rt.scan_impl)
+    with jax.named_scope("norm"):
+        h = L.rms_norm(x, p["norm1"], cfg.norm_eps)
+    with jax.named_scope("mixer"):
+        if spec.mixer == "attn":
+            mix = L.apply_attention(p["attn"], h, spec.attn, cfg, positions,
+                                    q_chunk=rt.q_chunk,
+                                    attn_impl=rt.attn_impl)
+        else:
+            mix = L.apply_mamba(p["mamba"], h, cfg, scan_impl=rt.scan_impl)
     x = x + mix
     if memory is not None:
-        h = L.rms_norm(x, p["norm_cross"], cfg.norm_eps)
-        cross = L.apply_attention(
-            p["cross"], h, spec.attn, cfg, positions,
-            kv_override=(memory, mem_positions), causal=False,
-            q_chunk=rt.q_chunk, attn_impl="xla")
+        with jax.named_scope("norm"):
+            h = L.rms_norm(x, p["norm_cross"], cfg.norm_eps)
+        with jax.named_scope("mixer"):
+            cross = L.apply_attention(
+                p["cross"], h, spec.attn, cfg, positions,
+                kv_override=(memory, mem_positions), causal=False,
+                q_chunk=rt.q_chunk, attn_impl="xla")
         x = x + cross
     if spec.ffn != "none":
-        h = L.rms_norm(x, p["norm2"], cfg.norm_eps)
-        f = jnp.zeros_like(x)
-        if spec.ffn in ("moe", "moe_dense"):
-            mo, a = L.apply_moe(p["moe"], h, cfg)
-            f = f + mo
-            aux = aux + a
-        if spec.ffn in ("dense", "moe_dense"):
-            f = f + L.apply_mlp(p["mlp"], h, cfg.act)
+        with jax.named_scope("norm"):
+            h = L.rms_norm(x, p["norm2"], cfg.norm_eps)
+        with jax.named_scope("ffn"):
+            f = jnp.zeros_like(x)
+            if spec.ffn in ("moe", "moe_dense"):
+                mo, a = L.apply_moe(p["moe"], h, cfg)
+                f = f + mo
+                aux = aux + a
+            if spec.ffn in ("dense", "moe_dense"):
+                f = f + L.apply_mlp(p["mlp"], h, cfg.act)
         x = x + f
     return x, aux
 
@@ -197,8 +204,9 @@ def _run_blocks(params, x, positions, cfg, rt, memory=None, mem_positions=None):
         policy = (jax.checkpoint_policies.nothing_saveable if rt.remat == "full"
                   else jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
         body_fn = jax.checkpoint(body, policy=policy, prevent_cse=False)
-    (x, aux), _ = lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
-                           tuple(params["blocks"]))
+    with jax.named_scope("blocks"):
+        (x, aux), _ = lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
+                               tuple(params["blocks"]))
     return x, aux
 
 
@@ -259,13 +267,15 @@ def forward(params: Params, batch: Dict[str, jax.Array], cfg: ArchConfig,
         tokens = batch["tokens"]
         B, S = tokens.shape
         positions = jnp.arange(S)[None, :]
-        x = L._cs(_embed(params, tokens, cfg), "dp", None, None)
+        with jax.named_scope("embed"):
+            x = L._cs(_embed(params, tokens, cfg), "dp", None, None)
         memory = mem_pos = None
         if cfg.enc_dec:
             memory, mem_pos = _encode(params, batch["frames"].astype(x.dtype),
                                       cfg, rt)
         x, aux = _run_blocks(params, x, positions, cfg, rt, memory, mem_pos)
-        return L._cs(_logits(params, x, cfg), "dp", None, "tp"), aux
+        with jax.named_scope("head"):
+            return L._cs(_logits(params, x, cfg), "dp", None, "tp"), aux
     finally:
         L.set_shard_ctx(None)
 

@@ -23,6 +23,7 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
 def loss_fn(params, batch, cfg, rt: M.Runtime):
     """batch: tokens [B,S], labels [B,S] (+frames for enc-dec)."""
     logits, aux = M.forward(params, batch, cfg, rt)
-    ce = cross_entropy(logits, batch["labels"])
+    with jax.named_scope("head"):
+        ce = cross_entropy(logits, batch["labels"])
     total = ce + rt.aux_loss_weight * aux
     return total, {"ce": ce, "moe_aux": aux}

@@ -46,14 +46,18 @@ def train_step(state: Dict[str, Any], batch: Dict[str, jax.Array], *,
         g_acc, loss_acc = carry
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, mb, cfg, rt)
-        g_acc = jax.tree.map(lambda a, g: a + g.astype(acc_dt), g_acc, grads)
+        with jax.named_scope("grad_accum"):
+            g_acc = jax.tree.map(lambda a, g: a + g.astype(acc_dt), g_acc,
+                                 grads)
         return (g_acc, loss_acc + loss), metrics["ce"]
 
-    g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dt), params)
+    with jax.named_scope("grad_accum"):
+        g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dt), params)
     (grads, loss_sum), ce = lax.scan(micro, (g0, jnp.zeros((), jnp.float32)),
                                      batch)
     accum = batch["tokens"].shape[0]
-    grads = jax.tree.map(lambda g: g / accum, grads)
+    with jax.named_scope("grad_accum"):
+        grads = jax.tree.map(lambda g: g / accum, grads)
 
     if compress_grads:
         # int8 blockwise quantize->dequantize straddling the DP reduction;
@@ -61,7 +65,9 @@ def train_step(state: Dict[str, Any], batch: Dict[str, jax.Array], *,
         grads = jax.tree.map(
             lambda g: quant.dequant(quant.quant(g.astype(jnp.float32))), grads)
 
-    new_params, new_opt, gnorm = adamw_update(params, grads, state["opt"], hp)
+    with jax.named_scope("optimizer"):
+        new_params, new_opt, gnorm = adamw_update(params, grads, state["opt"],
+                                                  hp)
     metrics = {"loss": loss_sum / accum, "ce": jnp.mean(ce),
                "grad_norm": gnorm}
     return ({"params": new_params, "opt": new_opt,

@@ -294,24 +294,6 @@ def test_metrics_snapshot_is_typed_and_frozen():
         m.ops["win"] = win       # frozen mapping view
 
 
-def test_legacy_stats_accessors_warn_and_delegate():
-    build, expected = linear_pipeline()
-    eng = Engine(build(), mode="step")
-    eng.run_to_completion()
-    m = eng.metrics()
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        ps = eng.process_stats()
-        detail = eng.op_stats_detail()
-        ws = eng.wire_stats()
-    deps = [w for w in caught if issubclass(w.category, DeprecationWarning)]
-    assert len(deps) == 3
-    assert all("Engine.metrics()" in str(w.message) for w in deps)
-    assert ps == {op: om.processed for op, om in m.ops.items()}
-    assert detail["win"]["txns"] == m.op("win").txns
-    assert ws == {}              # step mode: no byte wire
-
-
 def test_backend_query_stats_shim_warns():
     from repro.core.logstore import MemoryLogStore
     store = MemoryLogStore()
