@@ -82,7 +82,8 @@ def _loop_line(xplane):
 
 
 @pytest.fixture(scope="module")
-def traced_run(tmp_path_factory):
+def traced(tmp_path_factory):
+    """A tiny traced ``run_training`` and its ``.xplane.pb``."""
     from repro.launch.train import run_training
     d = tmp_path_factory.mktemp("spans")
     opts = jax.profiler.ProfileOptions()
@@ -96,6 +97,12 @@ def traced_run(tmp_path_factory):
         jax.profiler.stop_trace()
     xplane = glob.glob(str(d / "trace" / "**" / "*.xplane.pb"),
                        recursive=True)[-1]
+    return out, xplane
+
+
+@pytest.fixture(scope="module")
+def traced_run(traced):
+    out, xplane = traced
     return out, _loop_line(xplane)
 
 
@@ -124,6 +131,17 @@ def test_save_has_its_three_children(traced_run):
                   if n.startswith("ckpt.") and n != "ckpt.save"
                   and save[0] <= s and e <= save[1])
     assert [n for _, n in kids] == ["ckpt.pull", "ckpt.write", "ckpt.fsync"]
+
+
+def test_write_span_carries_the_leaf_bytes(traced):
+    out, xplane = traced
+    pd = jax.profiler.ProfileData.from_file(xplane)
+    (write,) = [dict(e.stats) for plane in pd.planes
+                if plane.name.startswith("/host:") for line in plane.lines
+                for e in line.events if e.name == "ckpt.write"]
+    _, state = out["store"].latest()
+    assert write["bytes"] == sum(a.nbytes for a in jax.tree.leaves(state))
+    assert write["bytes"] > 0
 
 
 def test_log_commits_are_spans_on_the_feed_and_the_ack(traced_run):
