@@ -6,45 +6,33 @@ attention counts the half of the score and value products that the mask
 keeps. Recomputation under remat does not count: it is work the model does
 not need. The embedding lookup is a gather and counts nothing. Elementwise
 work counts only where it is the layer's own arithmetic (the Mamba scan);
-norms, activations and the loss's softmax are left out.
+norms, activations and the loss's softmax are left out. Each layer part
+(``bench/layers/``) counts its own; the period repeats ``n_blocks`` times.
 """
 from __future__ import annotations
 
+from bench import layers
+
+
+def _parts(a: dict):
+    return [p for pair in layers.period(a) for p in pair]
+
 
 def matmul_params(a: dict) -> int:
-    """Weights that take part in a matrix product, per token."""
-    d, V, L = a["hidden_size"], a["vocab_size"], a["num_hidden_layers"]
-    if a["kind"] == "transformer":
-        h, kv, dh, f = (a["num_attention_heads"], a["num_key_value_heads"],
-                        a["head_dim"], a["intermediate_size"])
-        layer = d * h * dh + 2 * d * kv * dh + h * dh * d + 3 * d * f
-    else:
-        di, ds, dr = (a["intermediate_size"], a["state_size"],
-                      a["time_step_rank"])
-        layer = d * 2 * di + di * (dr + 2 * ds) + dr * di + di * d
-    return L * layer + d * V                       # layers + output head
+    """Weights that take part in a matrix product, per token: the layers
+    and the output head (tied or not)."""
+    per_period = sum(p.matmul_params(a) for p in _parts(a))
+    return layers.n_blocks(a) * per_period + a["hidden_size"] * a["vocab_size"]
 
 
-def attention_flops_fwd(a: dict, batch: int, seq: int) -> float:
-    """QK^T and PV of causal attention, forward: 2 products of
-    2*S*S*H*dh each, half of it under the mask."""
-    if a["kind"] != "transformer":
-        return 0.0
-    h, dh = a["num_attention_heads"], a["head_dim"]
-    return a["num_hidden_layers"] * batch * 2 * seq * seq * h * dh
-
-
-def scan_flops_fwd(a: dict, tokens: int) -> float:
-    """Selective scan per token and channel x state: exp(dt*A) (1), dt*u*B
-    (2), h = a*h + b (2), y += h*C (2); plus the depthwise conv (2 per tap)."""
-    if a["kind"] != "mamba":
-        return 0.0
-    di, ds, dc = a["intermediate_size"], a["state_size"], a["conv_kernel"]
-    return a["num_hidden_layers"] * tokens * (7 * di * ds + 2 * dc * di)
+def layer_flops_fwd(a: dict, batch: int, seq: int) -> int:
+    """The layers' forward FLOPs outside their weights' products: causal
+    attention's scores and values, the scan's elementwise work."""
+    return layers.n_blocks(a) * sum(p.flops_fwd(a, batch, seq)
+                                    for p in _parts(a))
 
 
 def train_step_flops(a: dict, batch: int, seq: int) -> float:
     tokens = batch * seq
-    fwd = (2.0 * matmul_params(a) * tokens + attention_flops_fwd(a, batch, seq)
-           + scan_flops_fwd(a, tokens))
-    return 3.0 * fwd
+    return 3.0 * (2.0 * matmul_params(a) * tokens
+                  + layer_flops_fwd(a, batch, seq))

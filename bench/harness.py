@@ -5,8 +5,13 @@ Everything that belongs to one configuration, traffic mix or metric is a
 file found by its name in ``BENCHMARK.json``:
 
 * ``bench/configs/<config>.json``: the configuration as run, its published
-  values, its training settings, the sizing numbers that turn ``--seconds``
-  into a number of steps, and the limits of the correctness comparison;
+  values, its ``period`` of layer parts, its training settings, the sizing
+  numbers that turn ``--seconds`` into a number of steps, and the limits of
+  the correctness comparison;
+* ``bench/layers/<part>.py``: one mixer or FFN that a ``period`` names, with
+  the keys it reads, the program's ``ArchConfig`` fields it stands for, its
+  seeded leaves, its float32 forward pass and its FLOPs (see
+  ``bench/layers/__init__.py``);
 * ``bench/traffic/<traffic>.json``: the training job's arrivals;
 * ``bench/metrics/<metric>.py``: a reader ``read(run) -> float | None`` of
   one metric from the run's record (see ``RunRecord``).
@@ -43,7 +48,7 @@ import tempfile
 import time
 import traceback
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, get_args, get_type_hints
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -78,44 +83,55 @@ def load_traffic(name: str) -> dict:
 
 def dims(cfg: dict) -> dict:
     """The configuration's shapes under one set of keys, for the reference
-    and the FLOP count."""
-    a = {k: cfg[k] for k in ("kind", "hidden_size", "num_hidden_layers",
-                             "vocab_size")}
+    and the FLOP count: the common keys, ``period`` as (mixer, ffn) names,
+    and the keys that the period's parts read."""
+    from bench import layers
+    period = [(p["mixer"], p["ffn"]) for p in cfg["period"]]
+    if not period or cfg["num_hidden_layers"] % len(period):
+        raise ValueError(f"bench: {cfg['name']}: num_hidden_layers "
+                         f"{cfg['num_hidden_layers']} is not a multiple of "
+                         f"the period's {len(period)} layers")
+    a = {k: cfg[k] for k in ("hidden_size", "num_hidden_layers", "vocab_size",
+                             "tie_word_embeddings")}
     a["rms_norm_eps"] = cfg.get("rms_norm_eps", cfg.get("layer_norm_epsilon"))
-    keys = (("num_attention_heads", "num_key_value_heads", "head_dim",
-             "intermediate_size", "rope_theta")
-            if cfg["kind"] == "transformer" else
-            ("intermediate_size", "state_size", "conv_kernel",
-             "time_step_rank"))
-    a.update({k: cfg[k] for k in keys})
+    a["period"] = period
+    for pair in layers.period(a):
+        for part in pair:
+            a.update({k: cfg[k] for k in part.KEYS})
     return a
 
 
 def arch_config(cfg: dict):
-    """The program's ``ArchConfig`` for the configuration file."""
-    from repro.configs.base import (ArchConfig, AttnSpec, LayerSpec,
-                                    MambaSpec)
+    """The program's ``ArchConfig`` for the configuration file: ``block`` is
+    the period, and each part sets the fields it stands for. Parts that set
+    ``family`` differently make the model a ``hybrid``."""
+    from repro.configs.base import ArchConfig, LayerSpec
+    from bench import layers
     a = dims(cfg)
-    common = dict(name=cfg["name"], n_layers=a["num_hidden_layers"],
-                  d_model=a["hidden_size"], vocab=a["vocab_size"],
-                  norm_eps=a["rms_norm_eps"],
-                  tie_embeddings=cfg["tie_word_embeddings"],
-                  source=cfg["source"])
-    if cfg["kind"] == "transformer":
-        return ArchConfig(
-            family="dense", n_heads=a["num_attention_heads"],
-            n_kv_heads=a["num_key_value_heads"], d_head=a["head_dim"],
-            d_ff=a["intermediate_size"], rope_theta=a["rope_theta"],
-            act=cfg["hidden_act"],
-            block=(LayerSpec(mixer="attn", ffn="dense", attn=AttnSpec()),),
-            **common)
-    return ArchConfig(
-        family="ssm", n_heads=0, n_kv_heads=0, d_head=0, d_ff=0,
-        block=(LayerSpec(mixer="mamba", ffn="none"),),
-        mamba=MambaSpec(d_state=a["state_size"], d_conv=a["conv_kernel"],
-                        expand=a["intermediate_size"] // a["hidden_size"],
-                        dt_rank=a["time_step_rank"]),
-        subquadratic=True, **common)
+    fields, block = {}, []
+    for mixer, ffn in layers.period(a):
+        block.append(LayerSpec(mixer=mixer.SPEC, ffn=ffn.SPEC))
+        for part in (mixer, ffn):
+            for k, v in part.arch_fields(a).items():
+                if fields.get(k, v) != v:
+                    if k != "family":
+                        raise ValueError(f"bench: {cfg['name']}: layer parts"
+                                         f" set {k} to {fields[k]!r} and "
+                                         f"{v!r}")
+                    v = "hybrid"
+                fields[k] = v
+    hints = get_type_hints(ArchConfig)
+    for k, v in fields.items():
+        if isinstance(v, dict):        # a nested spec, such as ``mamba``
+            fields[k] = next(t for t in get_args(hints[k])
+                             if dataclasses.is_dataclass(t))(**v)
+    return ArchConfig(name=cfg["name"], n_layers=a["num_hidden_layers"],
+                      d_model=a["hidden_size"], vocab=a["vocab_size"],
+                      norm_eps=a["rms_norm_eps"],
+                      tie_embeddings=cfg["tie_word_embeddings"],
+                      source=cfg["source"], block=tuple(block),
+                      **{"n_heads": 0, "n_kv_heads": 0, "d_head": 0, "d_ff": 0,
+                         **fields})
 
 
 def config_departures(cfg: dict, settings) -> List[str]:

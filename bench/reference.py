@@ -10,8 +10,10 @@ configuration states, and the AdamW moments are stored between steps in the
 configuration's moment dtype (int8 with a per-row scale). Departures from the
 program's own arithmetic are listed in PERF.md.
 
-The model runs layer by layer (forward keeps each layer's input; backward
-re-runs one layer under ``jax.vjp``) and the output head in blocks of
+The architecture is the configuration's ``period`` of layer parts
+(``bench/layers/``). The model runs layer by layer (forward keeps each
+layer's input; backward re-runs one layer under ``jax.vjp``; one compiled
+program per position of the period) and the output head in blocks of
 tokens, so that a 1.9 B-parameter model, its stored moments and its float32
 gradient fit on one 16 GB chip: gradients go to the host as each layer
 finishes.
@@ -30,6 +32,9 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import layers
+from bench.layers import normal
 
 F32 = jnp.float32
 HEAD_BLOCK = 1024     # tokens per call of the output head: its float32 logits
@@ -60,61 +65,40 @@ def corpus_batch(seed: int, step: int, vocab: int, seq_len: int,
 # ---------------------------------------------------------------------------
 
 
-def _normal(key, shape, scale, dtype, divide=False):
-    x = jax.random.normal(key, shape, F32)
-    if scale is not None:
-        x = x / scale if divide else x * scale
-    return x.astype(dtype)
-
-
-def _layer_init(key, a: dict, dtype) -> Dict[str, jax.Array]:
+def _layer_init(key, a: dict, mixer, ffn, dtype) -> Dict[str, jax.Array]:
+    """One layer's leaves: ``norm1`` and the mixer's, then ``norm2`` and the
+    FFN's where it has any; each part from its sub-key of the program's
+    eight."""
     d = a["hidden_size"]
     ks = jax.random.split(key, 8)
     p = {"norm1": jnp.zeros((d,), dtype)}
-    if a["kind"] == "transformer":
-        h, kv, dh, f = (a["num_attention_heads"], a["num_key_value_heads"],
-                        a["head_dim"], a["intermediate_size"])
-        ka = jax.random.split(ks[0], 4)
-        s = 1.0 / math.sqrt(d)
-        p["wq"] = _normal(ka[0], (d, h, dh), s, dtype)
-        p["wk"] = _normal(ka[1], (d, kv, dh), s, dtype)
-        p["wv"] = _normal(ka[2], (d, kv, dh), s, dtype)
-        p["wo"] = _normal(ka[3], (h, dh, d), 1.0 / math.sqrt(h * dh), dtype)
+    p.update(mixer.init(ks[mixer.SUBKEY], a, dtype))
+    if ffn.SUBKEY is not None:
         p["norm2"] = jnp.zeros((d,), dtype)
-        km = jax.random.split(ks[2], 3)
-        p["w1"] = _normal(km[0], (d, f), 1 / math.sqrt(d), dtype)
-        p["w3"] = _normal(km[1], (d, f), 1 / math.sqrt(d), dtype)
-        p["w2"] = _normal(km[2], (f, d), 1 / math.sqrt(f), dtype)
-    else:
-        di, ds, dc, dr = (a["intermediate_size"], a["state_size"],
-                          a["conv_kernel"], a["time_step_rank"])
-        km = jax.random.split(ks[0], 6)
-        p["in_proj"] = _normal(km[0], (d, 2 * di), 1 / math.sqrt(d), dtype)
-        p["conv_w"] = _normal(km[1], (dc, di), 1 / math.sqrt(dc), dtype)
-        p["x_proj"] = _normal(km[2], (di, dr + 2 * ds), 1 / math.sqrt(di),
-                              dtype)
-        p["dt_proj"] = _normal(km[3], (dr, di), 1 / math.sqrt(dr), dtype)
-        p["dt_bias"] = jnp.zeros((di,), F32) + jnp.log(jnp.expm1(0.01))
-        p["A_log"] = jnp.log(jnp.broadcast_to(
-            jnp.arange(1, ds + 1, dtype=F32), (di, ds))).astype(F32)
-        p["D"] = jnp.ones((di,), F32)
-        p["out_proj"] = _normal(km[5], (di, d), 1 / math.sqrt(di), dtype)
+        p.update(ffn.init(ks[ffn.SUBKEY], a, dtype))
     return p
 
 
-def init_params(seed: int, a: dict, dtype) -> Dict[str, jax.Array]:
-    """The seeded weights: ``embed`` [V, d], ``unembed`` [d, V],
-    ``final_norm`` [d] and every layer leaf stacked over the depth."""
+def init_params(seed: int, a: dict, dtype) -> Dict[str, object]:
+    """The seeded weights: ``embed`` [V, d], ``final_norm`` [d], ``unembed``
+    [d, V] where the head is not tied, and ``layers``: per position of the
+    period, its leaves stacked over the blocks. Position ``i`` is made from
+    key ``8 + i`` of the seed's, as the program makes it."""
     key = jax.random.PRNGKey(seed)
-    ks = jax.random.split(key, 9)
-    d, V, L = a["hidden_size"], a["vocab_size"], a["num_hidden_layers"]
-    layer_keys = jax.random.split(ks[8], L)
-    layers = jax.vmap(lambda k: _layer_init(k, a, dtype))(layer_keys)
-    return {"embed": _normal(ks[0], (V, d), None, dtype),
-            "final_norm": jnp.zeros((d,), dtype),
-            "unembed": _normal(ks[1], (d, V), math.sqrt(d), dtype,
-                               divide=True),
-            "layers": layers}
+    per = layers.period(a)
+    ks = jax.random.split(key, 8 + len(per))
+    d, V = a["hidden_size"], a["vocab_size"]
+    n = layers.n_blocks(a)
+    out = {"embed": normal(ks[0], (V, d), None, dtype),
+           "final_norm": jnp.zeros((d,), dtype),
+           "layers": [jax.vmap(partial(_layer_init, a=a, mixer=m, ffn=f,
+                                       dtype=dtype))(
+                          jax.random.split(ks[8 + i], n))
+                      for i, (m, f) in enumerate(per)]}
+    if not a["tie_word_embeddings"]:
+        out["unembed"] = normal(ks[1], (d, V), math.sqrt(d), dtype,
+                                divide=True)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -165,102 +149,32 @@ def rms_norm(x, scale, eps):
             * (1.0 + scale.astype(F32)))
 
 
-def rope(x, theta):
-    """Rotate-half RoPE over the last dim; x [B, S, H, D], positions 0..S-1."""
-    S, D = x.shape[1], x.shape[-1]
-    half = D // 2
-    freqs = theta ** (-jnp.arange(half, dtype=F32) / half)
-    ang = jnp.arange(S, dtype=F32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _attention(p, h, a, mm, q_block=512):
-    B, S, _ = h.shape
-    H, KV, dh = (a["num_attention_heads"], a["num_key_value_heads"],
-                 a["head_dim"])
-    q = rope(mm("bsd,dhk->bshk", h, p["wq"]), a["rope_theta"])
-    k = rope(mm("bsd,dhk->bshk", h, p["wk"]), a["rope_theta"])
-    v = mm("bsd,dhk->bshk", h, p["wv"])
-    k = jnp.repeat(k, H // KV, axis=2)       # query head j reads kv head j//g
-    v = jnp.repeat(v, H // KV, axis=2)
-    qb = min(q_block, S)
-    nb = S // qb
-
-    @jax.checkpoint
-    def block(args):
-        qc, start = args                      # [B, qb, H, dh]
-        sc = mm("bqhd,bkhd->bhqk", qc, k) / math.sqrt(dh)
-        qpos = start + jnp.arange(qb)
-        keep = jnp.arange(S)[None, :] <= qpos[:, None]
-        sc = jnp.where(keep[None, None], sc, -jnp.inf)
-        return mm("bhqk,bkhd->bqhd", jax.nn.softmax(sc, axis=-1), v)
-
-    qs = jnp.moveaxis(q.reshape(B, nb, qb, H, dh), 1, 0)
-    out = jax.lax.map(block, (qs, jnp.arange(nb) * qb))
-    out = jnp.moveaxis(out, 0, 1).reshape(B, S, H, dh)
-    return mm("bshk,hkd->bsd", out, p["wo"])
-
-
-def _mamba(p, h, a, mm, chunk=256):
-    B, S, _ = h.shape
-    di, ds, dc, dr = (a["intermediate_size"], a["state_size"],
-                      a["conv_kernel"], a["time_step_rank"])
-    xz = mm("bsd,de->bse", h, p["in_proj"])
-    u, z = xz[..., :di], xz[..., di:]
-    up = jnp.concatenate([jnp.zeros((B, dc - 1, di), F32), u], axis=1)
-    w = p["conv_w"].astype(F32)
-    conv = sum(up[:, i:i + S] * w[i] for i in range(dc))  # causal depthwise
-    u = jax.nn.silu(conv)
-    dbc = mm("bsi,ie->bse", u, p["x_proj"])
-    dt_r, Bc, Cc = dbc[..., :dr], dbc[..., dr:dr + ds], dbc[..., dr + ds:]
-    dt = jax.nn.softplus(mm("bsr,ri->bsi", dt_r, p["dt_proj"])
-                         + p["dt_bias"].astype(F32))
-    A = -jnp.exp(p["A_log"].astype(F32))                    # [di, ds]
-
-    def one(state, xs):                        # one time step, in order
-        dt_t, u_t, b_t, c_t = xs               # [B,di] [B,di] [B,ds] [B,ds]
-        state = (jnp.exp(dt_t[..., None] * A) * state
-                 + (dt_t * u_t)[..., None] * b_t[:, None, :])
-        return state, jnp.einsum("bin,bn->bi", state, c_t,
-                                 precision=jax.lax.Precision.HIGHEST)
-
-    @jax.checkpoint
-    def chunk_steps(state, xs):                # keeps one state per chunk
-        return jax.lax.scan(one, state, xs)
-
-    chunk = min(chunk, S) if S % min(chunk, S) == 0 else S
-
-    def t_major(t):                            # [B,S,...] -> [S/c, c, B, ...]
-        t = jnp.moveaxis(t, 1, 0)
-        return t.reshape((S // chunk, chunk) + t.shape[1:])
-
-    _, y = jax.lax.scan(chunk_steps, jnp.zeros((B, di, ds), F32),
-                        tuple(t_major(t) for t in (dt, u, Bc, Cc)))
-    y = jnp.moveaxis(y.reshape((S, B, di)), 0, 1)
-    y = (y + u * p["D"].astype(F32)) * jax.nn.silu(z)
-    return mm("bsi,id->bsd", y, p["out_proj"])
-
-
-def layer_forward(p, x, a, precision="f32"):
+def layer_forward(p, x, a, pos, precision="f32"):
+    """Layer at position ``pos`` of the period: the mixer's residual branch,
+    then the FFN's. Returns the output and the sum of the parts' loss terms
+    (``None`` where no part has one)."""
+    mixer, ffn = layers.period(a)[pos]
     mm = _mm(precision)
     eps = a["rms_norm_eps"]
-    if a["kind"] == "transformer":
-        x = x + _attention(p, rms_norm(x, p["norm1"], eps), a, mm)
-        h = rms_norm(x, p["norm2"], eps)
-        g = jax.nn.silu(mm("bsd,df->bsf", h, p["w1"]))
-        return x + mm("bsf,fd->bsd", g * mm("bsd,df->bsf", h, p["w3"]),
-                      p["w2"])
-    return x + _mamba(p, rms_norm(x, p["norm1"], eps), a, mm)
+    y, aux = mixer.forward(p, rms_norm(x, p["norm1"], eps), a, mm)
+    x, terms = x + y, [aux]
+    if ffn.SUBKEY is not None:
+        y, aux = ffn.forward(p, rms_norm(x, p["norm2"], eps), a, mm)
+        x, terms = x + y, terms + [aux]
+    terms = [t for t in terms if t is not None]
+    return x, (sum(terms) if terms else None)
 
 
 def head_loss_sum(head, x, labels, a, z_loss, precision="f32"):
     """Sum over the rows of x of the next-token loss (cross-entropy plus the
-    z-loss ``z_loss * logsumexp**2``); x [B, S, d], labels [B, S]."""
+    z-loss ``z_loss * logsumexp**2``); x [B, S, d], labels [B, S]. A tied
+    head (no ``unembed``) reads the embedding, with no scale."""
     mm = _mm(precision)
     h = rms_norm(x, head["final_norm"], a["rms_norm_eps"])
-    logits = mm("bsd,dv->bsv", h, head["unembed"])
+    if "unembed" in head:
+        logits = mm("bsd,dv->bsv", h, head["unembed"])
+    else:
+        logits = mm("bsd,vd->bsv", h, head["embed"])
     lse = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return jnp.sum(lse - gold + z_loss * lse * lse)
@@ -284,16 +198,25 @@ def _moment_codec(dtype: str):
 
 
 def flat_leaves(tree) -> Dict[str, jax.Array]:
-    """{leaf name: leaf} of a parameter-shaped tree, the layer leaves stacked
-    over the depth (``embed``, ``final_norm``, ``unembed`` and e.g. ``wq``)."""
+    """{leaf name: leaf} of a parameter-shaped tree, the reference's or the
+    program's, the layer leaves stacked over the blocks: ``embed``,
+    ``final_norm``, ``unembed`` where the head is not tied, and e.g. ``wq``;
+    with more than one position in the period, ``<position>.wq``."""
     if "layers" in tree:
-        out = dict(tree["layers"])
+        positions = tree["layers"]
     else:                                   # the program's nesting
-        out = {}
+        positions = []
         for block in tree["blocks"]:
+            flat = {}
             for k, v in block.items():
-                out.update(v if isinstance(v, dict) else {k: v})
-    out.update({k: tree[k] for k in ("embed", "final_norm", "unembed")})
+                flat.update(v if isinstance(v, dict) else {k: v})
+            positions.append(flat)
+    out = {}
+    for i, pos in enumerate(positions):
+        out.update({(f"{i}.{k}" if len(positions) > 1 else k): v
+                    for k, v in pos.items()})
+    out.update({k: tree[k] for k in ("embed", "final_norm", "unembed")
+                if k in tree})
     return out
 
 
@@ -326,19 +249,21 @@ class Reference:
         a_, prec = self.a, precision
         z = float(train["z_loss"])
 
-        def layer_at(layers, i):     # float32 copy: float32 gradients
-            return jax.tree.map(lambda t: t[i].astype(F32), layers)
+        def layer_at(stack, i):      # float32 copy: float32 gradients
+            return jax.tree.map(lambda t: t[i].astype(F32), stack)
 
-        @jax.jit
-        def fwd(layers, i, x):
-            lp = layer_at(layers, i)
-            return layer_forward(lp, x, a_, prec)
+        # ``stack``: one position's leaves; ``i``: the block; ``pos``: the
+        # position, static, so each position compiles once
+        @partial(jax.jit, static_argnums=3)
+        def fwd(stack, i, x, pos):
+            return layer_forward(layer_at(stack, i), x, a_, pos, prec)
 
-        @jax.jit
-        def bwd(layers, i, x, g):
-            lp = layer_at(layers, i)
-            _, pull = jax.vjp(lambda q, y: layer_forward(q, y, a_, prec), lp, x)
-            gp, gx = pull(g)
+        @partial(jax.jit, static_argnums=4)
+        def bwd(stack, i, x, g, pos):
+            lp = layer_at(stack, i)
+            (_, aux), pull = jax.vjp(
+                lambda q, y: layer_forward(q, y, a_, pos, prec), lp, x)
+            gp, gx = pull((g, None if aux is None else jnp.ones((), F32)))
             sq = sum(jnp.sum(jnp.square(t)) for t in jax.tree.leaves(gp))
             return gp, gx, sq
 
@@ -385,12 +310,17 @@ class Reference:
         x_in, labels = toks[:, :-1], toks[:, 1:]
         n_tok = labels.size
         P = self.params
+        n_pos = len(P["layers"])
         x = jnp.take(P["embed"], x_in, axis=0).astype(F32)
-        xs = []
-        for i in range(self.a["num_hidden_layers"]):
+        xs, aux_sum = [], 0.0
+        for layer in range(self.a["num_hidden_layers"]):
+            pos, block = layer % n_pos, layer // n_pos
             xs.append(x)
-            x = self._fwd(P["layers"], i, x)
-        hp = {"final_norm": P["final_norm"], "unembed": P["unembed"]}
+            x, aux = self._fwd(P["layers"][pos], block, x, pos)
+            if aux is not None:
+                aux_sum += float(aux)
+        head_w = "unembed" if "unembed" in P else "embed"
+        hp = {"final_norm": P["final_norm"], head_w: P[head_w]}
         loss_sum, g_head, g = 0.0, None, []
         blk = min(HEAD_BLOCK, x.shape[1])
         for b in range(x.shape[0]):          # a block of one sequence at a time
@@ -407,27 +337,32 @@ class Reference:
         scale = 1.0 / n_tok
         gx = jnp.concatenate(g, axis=0) * scale
         g_head = {k: v * scale for k, v in g_head.items()}
+        g_tied = g_head.pop("embed", None)   # a tied head's part of embed's
         sq = [jnp.sum(jnp.square(v)) for v in g_head.values()]
         host = {k: np.asarray(v) for k, v in g_head.items()}
         del g_head
-        layers = {}
-        for i in reversed(range(self.a["num_hidden_layers"])):
-            gp, gx, s = self._bwd(P["layers"], i, xs[i], gx)
-            xs[i] = None
+        nb = layers.n_blocks(self.a)
+        grads = [{} for _ in range(n_pos)]
+        for layer in reversed(range(self.a["num_hidden_layers"])):
+            pos, block = layer % n_pos, layer // n_pos
+            gp, gx, s = self._bwd(P["layers"][pos], block, xs[layer], gx, pos)
+            xs[layer] = None
             sq.append(s)
             for k, v in jax.device_get(gp).items():
-                if k not in layers:
-                    layers[k] = np.empty((len(xs),) + v.shape, F32)
-                layers[k][i] = v
+                if k not in grads[pos]:
+                    grads[pos][k] = np.empty((nb,) + v.shape, F32)
+                grads[pos][k][block] = v
         ge = self._embed_grad(x_in.reshape(-1), gx.reshape(-1, gx.shape[-1]),
                               self.a["vocab_size"])
+        if g_tied is not None:
+            ge = ge + g_tied
         sq.append(jnp.sum(jnp.square(ge)))
         gnorm = math.sqrt(float(sum(float(v) for v in sq)))
-        host["layers"] = layers
+        host["layers"] = grads
         host["embed"] = np.asarray(ge)
         self.grads = host
         self.scale = min(1.0, self.opt["clip_norm"] / (gnorm + 1e-9))
-        return loss_sum / n_tok, gnorm
+        return loss_sum / n_tok + aux_sum, gnorm
 
     def update(self, move: bool = True):
         """AdamW step k (= number of updates so far, plus one) with the last

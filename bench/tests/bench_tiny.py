@@ -22,18 +22,17 @@ atexit.register(shutil.rmtree, _CACHE, ignore_errors=True)
 def tiny_spec() -> dict:
     spec = copy.deepcopy(harness.load_spec())
     spec["configs"] = [
-        {"name": "tiny-lm", "file": "bench/tests/configs/tiny-lm.json"},
-        {"name": "tiny-mamba", "file": "bench/tests/configs/tiny-mamba.json"}]
+        {"name": n, "file": f"bench/tests/configs/{n}.json"}
+        for n in ("tiny-lm", "tiny-mamba", "tiny-hybrid")]
     spec["workloads"] = [
-        {"name": "lm.steady", "config": "tiny-lm", "traffic": "steady",
-         "chips": 1},
-        {"name": "mamba.steady", "config": "tiny-mamba", "traffic": "steady",
-         "chips": 1}]
+        {"name": f"{n}.steady", "config": f"tiny-{n}", "traffic": "steady",
+         "chips": 1} for n in ("lm", "mamba", "hybrid")]
     rename = {"internlm2-1.8b": "lm", "falcon-mamba-7b.l8": "mamba"}
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
             m["workloads"] = [rename[w.rsplit(".", 1)[0]] + "." +
                               w.rsplit(".", 1)[1] for w in m["workloads"]]
+            m["workloads"].append("hybrid.steady")
     return spec
 
 

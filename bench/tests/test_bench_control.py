@@ -19,7 +19,7 @@ def _cfg(name):
         return json.load(f)
 
 
-@pytest.mark.parametrize("name", ["tiny-lm", "tiny-mamba"])
+@pytest.mark.parametrize("name", ["tiny-lm", "tiny-mamba", "tiny-hybrid"])
 def test_control_and_faults_fail_a_limit(name):
     cfg = _cfg(name)
     readings = control.readings(cfg, bench_tiny.SEED,

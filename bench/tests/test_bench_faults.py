@@ -25,6 +25,14 @@ def test_sound_run_is_correct():
     assert {"gnorm_gap", "grad_leaf_gap", "change1_leaf_gap"} <= set(r["checks"])
 
 
+def test_sound_hybrid_run_is_correct():
+    # a period of two kinds of layer, made only of the shipped parts
+    r = bench_tiny.run("hybrid.steady")
+    assert r["correct"], r["checks"]
+    assert r["metrics"]["train_tokens_per_s"]["value"] > 0
+    assert {"gnorm_gap", "grad_leaf_gap", "change1_leaf_gap"} <= set(r["checks"])
+
+
 def test_half_the_batch_left_out_is_not_correct(monkeypatch):
     def half(step, state, batch):
         b = batch["tokens"].shape[1] // 2

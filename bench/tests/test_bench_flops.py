@@ -8,9 +8,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 from bench import flops, harness  # noqa: E402
 
 
-def _cfg(name):
+def _cfg(name, where="configs"):
     return harness.dims(harness.load_json(
-        harness.BENCH / "configs" / f"{name}.json"))
+        harness.BENCH / where / f"{name}.json"))
 
 
 def test_internlm2_step_flops_by_hand():
@@ -24,6 +24,7 @@ def test_internlm2_step_flops_by_hand():
     # causal QK^T and PV: 2 products x 2*S*S*H*dh / 2, per sequence, layer
     attn = 24 * 2 * (2 * 2 * 4096 * 4096 * 16 * 128 // 2)
     want = 3 * (2 * weights * tokens + attn)
+    assert flops.layer_flops_fwd(a, 2, 4096) == attn
     assert flops.train_step_flops(a, 2, 4096) == want
     assert abs(want - 9.3432e13) / want < 1e-3
 
@@ -38,4 +39,23 @@ def test_falcon_mamba_step_flops_by_hand():
     tokens = 2 * 512
     scan = 8 * tokens * (7 * 8192 * 16 + 2 * 4 * 8192)
     assert flops.train_step_flops(a, 2, 512) == 3 * (2 * weights * tokens + scan)
-    assert flops.attention_flops_fwd(a, 2, 512) == 0
+    assert flops.layer_flops_fwd(a, 2, 512) == scan
+
+
+def test_tiny_hybrid_step_flops_by_hand():
+    a = _cfg("tiny-hybrid", "tests/configs")
+    # a period of two layers, twice: Mamba (in_proj 64 x 256, x_proj
+    # 128 x (4 + 8), dt_proj 4 x 128, out_proj 128 x 64) and attention
+    # (q 64x64, k and v 64x32 each, o 64x64), each with an MLP 3 x 64x128;
+    # then the output head 64 x 256
+    mamba = 64 * 256 + 128 * 12 + 4 * 128 + 128 * 64
+    attention = 64 * 64 * 2 + 64 * 32 * 2
+    mlp = 3 * 64 * 128
+    weights = 2 * (mamba + attention + 2 * mlp) + 64 * 256
+    assert flops.matmul_params(a) == weights == 192_512
+    tokens = 2 * 128
+    scan = 2 * tokens * (7 * 128 * 4 + 2 * 4 * 128)
+    attn = 2 * 2 * (2 * 2 * 128 * 128 * 4 * 16 // 2)
+    assert flops.layer_flops_fwd(a, 2, 128) == scan + attn
+    assert flops.train_step_flops(a, 2, 128) == 3 * (2 * weights * tokens
+                                                     + scan + attn)

@@ -19,13 +19,13 @@ B1 = 0.9
 def _tree(rng, scale):
     def leaf(*shape):
         return jnp.asarray(rng.standard_normal(shape) * scale, jnp.bfloat16)
-    return {"layers": {"w": leaf(3, 10, 12), "norm1": leaf(3, 12)},
+    return {"layers": [{"w": leaf(3, 10, 12), "norm1": leaf(3, 12)}],
             "embed": leaf(30, 12), "final_norm": leaf(12),
             "unembed": leaf(12, 30)}
 
 
 def _flat(tree):
-    return {**tree["layers"],
+    return {**tree["layers"][0],
             **{k: tree[k] for k in ("embed", "final_norm", "unembed")}}
 
 
@@ -46,12 +46,14 @@ def test_change_norms_from_the_copies_are_the_states():
     copy, read = harness.state_readers(B1)
     rng = np.random.default_rng(3)
     init, d1, d2 = _tree(rng, 1.0), _tree(rng, 0.01), _tree(rng, 0.02)
-    p1 = {"layers": {k: v + d1["layers"][k] for k, v in init["layers"].items()},
+    p1 = {"layers": [{k: v + d1["layers"][0][k]
+                      for k, v in init["layers"][0].items()}],
           **{k: init[k] + d1[k] for k in ("embed", "final_norm", "unembed")}}
-    p2 = {"layers": {k: v + d2["layers"][k] for k, v in init["layers"].items()},
+    p2 = {"layers": [{k: v + d2["layers"][0][k]
+                      for k, v in init["layers"][0].items()}],
           **{k: init[k] + d2[k] for k in ("embed", "final_norm", "unembed")}}
-    m1 = {"layers": {k: quant.quant(v.astype(jnp.float32))
-                     for k, v in d1["layers"].items()},
+    m1 = {"layers": [{k: quant.quant(v.astype(jnp.float32))
+                      for k, v in d1["layers"][0].items()}],
           **{k: d1[k] for k in ("embed", "final_norm", "unembed")}}
     r = read(copy(init), copy(m1), copy(p1), copy(p2))
     fi = _flat(init)
