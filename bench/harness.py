@@ -14,7 +14,8 @@ file found by its name in ``BENCHMARK.json``:
   ``bench/layers/__init__.py``);
 * ``bench/traffic/<traffic>.json``: the training job's arrivals;
 * ``bench/metrics/<metric>.py``: a reader ``read(run) -> float | None`` of
-  one metric from the run's record (see ``RunRecord``).
+  one metric from the run's record (see ``RunRecord``); a reader with
+  nothing to read returns None, and the run leaves that metric out.
 
 A run is one call of the program's own entry, ``repro.launch.train.
 run_training``, in this process. Set-up builds the train state and the
@@ -27,7 +28,9 @@ takes (and, before the first step, copies the seeded weights to the host),
 and a stream for the loop's printed step log that copies the train state to
 the host at the step lines the comparison needs and marks the window's
 start. The comparison's readings are worked out from those copies after the
-window.
+window. A traced run also keeps the train step's ``op_name``s as it is
+compiled, and reduces the window's trace by loop span and by scope
+(``bench/spans.py``) before it deletes it.
 """
 from __future__ import annotations
 
@@ -48,7 +51,7 @@ import tempfile
 import time
 import traceback
 from pathlib import Path
-from typing import Dict, List, Optional, get_args, get_type_hints
+from typing import Dict, List, Optional, Tuple, get_args, get_type_hints
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -198,7 +201,7 @@ class RunRecord:
     save_s: List[float]
     flops_per_step: float
     peak_flops_per_s: float
-    trace: Optional[dict] = None
+    trace: Optional[dict] = None      # bench.spans.reduce_trace's reduction
 
 
 def training_state():
@@ -465,8 +468,10 @@ def compare(cfg: dict, a: dict, seed: int, log: List[tuple],
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              require_accelerator: bool = True, spec: Optional[dict] = None,
-             t_start: Optional[float] = None) -> dict:
-    """One run of one cell. Returns the result line's object."""
+             t_start: Optional[float] = None
+             ) -> Tuple[dict, Optional[RunRecord]]:
+    """One run of one cell. Returns the result line's object and the record
+    that the metrics were read from (None where the run did not finish)."""
     t_start = time.perf_counter() if t_start is None else t_start
     age0 = process_age_s()
     spec = spec or load_spec()
@@ -484,6 +489,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     import repro.configs as configs
     from repro.launch import presets
     from repro.launch.train import run_training
+    from bench import spans
     from bench.flops import train_step_flops
     a = dims(cfg)
     arch = arch_config(cfg)
@@ -509,8 +515,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                     on_window=start_trace if trace else None)
     probes.install()
     stdout = StepLog(probes.on_step)
+    collect = spans.compiled_op_names() if trace else contextlib.nullcontext({})
     try:
-        with contextlib.redirect_stdout(stdout):
+        with contextlib.redirect_stdout(stdout), collect as op_names:
             out = run_training(
                 arch=cfg["name"], use_reduced=False, steps=n_steps,
                 seq_len=t["seq_len"], batch_size=t["batch_size"],
@@ -523,7 +530,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         traceback.print_exc()
         return {"correct": False, "attempted": len(probes.batches),
                 "failed": len(probes.batches), "metrics": {}, "device": dev,
-                "checks": {"run_error": {"value": 1, "limit": 0}}}
+                "checks": {"run_error": {"value": 1, "limit": 0}}}, None
     finally:
         if trace_t0[0] is not None:
             jax.profiler.stop_trace()
@@ -552,8 +559,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         from bench import trace as trace_mod
         path = trace_mod.find_xplane(trace_dir)
         if path is not None:
-            trace_summary = trace_mod.reduce(trace_mod.device_ops(path),
-                                             t_end - trace_t0[0])
+            trace_summary = spans.reduce_trace(path, op_names,
+                                               t_end - trace_t0[0])
         shutil.rmtree(trace_dir, ignore_errors=True)
         if trace_summary is not None:
             dev["busy_s"] = trace_summary["busy_s"]
@@ -584,7 +591,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     result = {"correct": all(c["value"] <= c["limit"] for c in checks.values()),
               "attempted": len(losses), "failed": 0, "metrics": metrics,
               "device": dev}
-    if trace_summary is not None:
+    if trace_summary is not None and "device_ops" in trace_summary:
         result["breakdown"] = {"device_ops": trace_summary["device_ops"],
                                "idle_gaps": trace_summary["idle_gaps"]}
     result["checks"] = checks
@@ -597,7 +604,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             "compile_s": timings["compile_s"], "departures": departures,
             "losses": losses}
     print("[bench] " + json.dumps(info), file=sys.stderr, flush=True)
-    return result
+    return result, record
 
 
 def main(argv=None) -> int:
@@ -610,8 +617,8 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     try:
-        result = run_cell(args.workload, args.seed, args.seconds,
-                          bool(args.trace), t_start=t_start)
+        result, _ = run_cell(args.workload, args.seed, args.seconds,
+                             bool(args.trace), t_start=t_start)
     except Exception:
         traceback.print_exc()
         return 1

@@ -7,32 +7,43 @@ train loop's line is the one that holds its ``train`` step events. The
 device step carries ``jax.named_scope`` names in each operation's
 ``op_name`` (``.../transpose(jvp(blocks))/.../mixer/dot_general``). A v5e
 trace does not carry ``op_name``: it is read from the compiled step's text
-(``hlo_op_names``) by the operation's name.
+(``compiled_op_names``) by the operation's program and name. The scopes
+known are the names the program's own source gives ``jax.named_scope``
+(``program_scopes``), so that a scope the program adds is known here with
+no change to this file; a reader of a scope the program does not open
+fails (``scope_ms``).
 
-``reduce_spans`` adds to ``bench/trace.py``'s busy time and idle share:
+``reduce_trace`` adds to ``bench/trace.py``'s busy time and idle share
+(``reduce_spans``):
 
 * each interval of the window in which the device ran nothing, cut at the
   loop's span boundaries, each piece labelled by the innermost span open
   over it, or ``NO_SPAN``;
-* each device operation's time under the innermost known scope of its
-  ``op_name``, or ``NO_SCOPE``;
+* device time by scope, inclusive: an operation counts for every known
+  scope on its ``op_name`` path, or for ``NO_SCOPE`` where it has none;
 * device time by the loop span open as each operation starts, and outside
   ``step.run``: the two clocks agree when a step's operations lie inside
-  its ``step.run``.
+  its ``step.run``;
+* the top operations named ``<scope>/<op>`` by their innermost scope.
 
-The per-layer readers in ``bench/metrics/`` read the program's own record
-of its spans instead (``recorded``), as the harness keeps no trace for
-them.
+The harness reduces the trace of every traced run so; the readers of
+``bench/metrics/`` take device time by scope from ``RunRecord`` and the
+host spans from the program's own record of them (``recorded``).
 """
 from __future__ import annotations
 
 import bisect
+import contextlib
+import functools
 import re
 from collections import defaultdict
-from typing import Dict, List, Optional, Tuple
+from pathlib import Path
+from typing import Dict, FrozenSet, Iterable, List, Optional, Tuple
 
-from bench.trace import CONTAINERS, busy_intervals
-from bench.trace import device_ops as trace_device_ops
+from bench import trace as trace_mod
+from bench.trace import busy_intervals
+
+SRC = Path(__file__).resolve().parent.parent / "src"
 
 STEP = "train"
 # the program's spans, as docs/metrics.md lists them
@@ -40,11 +51,14 @@ SPANS = (STEP, "step.compile", "ckpt.restore", "feed.get", "feed.put",
          "step.run", "step.sync", "ckpt.save", "ckpt.pull", "ckpt.write",
          "ckpt.fsync", "feed.ack", "trainer.restart", "feed.stop",
          "log.commit")
-SCOPES = ("embed", "blocks", "norm", "mixer", "ffn", "head", "grad_accum",
-          "optimizer")
 NO_SPAN = "host (no span)"
 NO_SCOPE = "(no scope)"
+# operations that contain others on the same line: they count towards busy
+# time, but their own totals would hide the operations inside them
+CONTAINERS = ("while", "conditional", "call")
 _SCOPE_PART = re.compile(r"(?:[\w-]+\()*([\w-]+)\)*")
+_NAMED_SCOPE = re.compile(r"""named_scope\(\s*["']([\w.-]+)["']""")
+_HLO_MODULE = re.compile(r"^\s*HloModule ([\w.-]+)", re.M)
 _HLO_OP_NAME = re.compile(
     r'^\s*(?:ROOT )?%?([\w.-]+) = .*?metadata=\{op_name="([^"]*)"', re.M)
 
@@ -71,18 +85,58 @@ def host_spans(xplane_path: str) -> List[List[Span]]:
     return out
 
 
-def device_ops_named(xplane_path: str, op_names: Dict[str, str]
-                     ) -> Dict[str, List[Op]]:
+def with_op_names(ops_by_device: Dict[str, List[Tuple[str, float, float,
+                                                      str]]],
+                  op_names: Dict[str, Dict[str, str]]) -> Dict[str, List[Op]]:
     """``bench.trace.device_ops`` with each operation's ``op_name`` from
-    ``op_names`` (``hlo_op_names`` of the compiled step; "" for an operation
-    of another program)."""
-    return {dev: [(name, s, d, op_names.get(name, "")) for name, s, d in ops]
-            for dev, ops in trace_device_ops(xplane_path).items()}
+    ``op_names`` (``compiled_op_names``), matched by its program and its
+    name: "" for an operation of a program not compiled there, whose names
+    (``copy.3``, ``fusion.1``) a compiled one may share."""
+    return {dev: [(name, s, d, op_names.get(module, {}).get(name, ""))
+                  for name, s, d, module in ops]
+            for dev, ops in ops_by_device.items()}
+
+
+def hlo_module_name(hlo_text: str) -> str:
+    """The program's name (``jit_train_step``) from its ``as_text()``."""
+    m = _HLO_MODULE.search(hlo_text)
+    return m.group(1) if m else ""
 
 
 def hlo_op_names(hlo_text: str) -> Dict[str, str]:
     """{instruction: op_name} from a compiled program's ``as_text()``."""
     return dict(_HLO_OP_NAME.findall(hlo_text))
+
+
+@contextlib.contextmanager
+def compiled_op_names():
+    """Yields a dict that collects, by program name, ``hlo_op_names`` of
+    every program compiled ahead of time (``jax.stages.Lowered.compile``)
+    inside the block: ``run_training`` compiles its train step so, and is
+    the only caller in a run that does."""
+    import jax
+    names: Dict[str, Dict[str, str]] = {}
+    compile_ = jax.stages.Lowered.compile
+
+    def compile_and_read_names(self, *args, **kwargs):
+        compiled = compile_(self, *args, **kwargs)
+        text = compiled.as_text()
+        names.setdefault(hlo_module_name(text), {}).update(hlo_op_names(text))
+        return compiled
+
+    jax.stages.Lowered.compile = compile_and_read_names
+    try:
+        yield names
+    finally:
+        jax.stages.Lowered.compile = compile_
+
+
+@functools.lru_cache(maxsize=None)
+def program_scopes(src: Path = SRC) -> FrozenSet[str]:
+    """The scopes the reduction knows: every name that the program's source
+    under ``src`` gives ``jax.named_scope``."""
+    return frozenset(name for path in sorted(src.rglob("*.py"))
+                     for name in _NAMED_SCOPE.findall(path.read_text()))
 
 
 def loop_spans(lines: List[List[Span]]) -> List[Span]:
@@ -155,15 +209,15 @@ class Segments:
         return [(label, ns / 1e9) for label, ns in out]
 
 
-def scope_of(name: str) -> str:
-    """The innermost known scope in an ``op_name`` path, wrappers such as
-    ``transpose(jvp(...))`` taken off."""
-    found = NO_SCOPE
+def scopes_on(name: str, known: Iterable[str]) -> List[str]:
+    """The known scopes on an ``op_name`` path, outermost first and each
+    once, wrappers such as ``transpose(jvp(...))`` taken off."""
+    out: List[str] = []
     for part in name.split("/"):
         m = _SCOPE_PART.fullmatch(part)
-        if m and m.group(1) in SCOPES:
-            found = m.group(1)
-    return found
+        if m and m.group(1) in known and m.group(1) not in out:
+            out.append(m.group(1))
+    return out
 
 
 def outside(ops: List[Op], loop: List[Span], name: str) -> float:
@@ -184,12 +238,14 @@ def outside(ops: List[Op], loop: List[Span], name: str) -> float:
 
 
 def reduce_spans(ops_by_device: Dict[str, List[Op]], lines: List[List[Span]],
-                 window_s: float, top: int = 10) -> Optional[dict]:
-    """The window's idle time by span, device time by scope and by span, the
-    device time outside ``step.run``, the operations with most time and
-    their scopes, and the longest idle pieces, each a mean over the devices.
-    None when the trace holds no device operation or no step of the
-    loop."""
+                 window_s: float, top: int = 10,
+                 known: Optional[Iterable[str]] = None) -> Optional[dict]:
+    """The window's idle time by span, device time by scope (inclusive) and
+    by span, the device time outside ``step.run``, the operations with most
+    time named by their innermost scope, and the longest idle pieces, each a
+    mean over the devices. None when the trace holds no device operation or
+    no step of the loop."""
+    known = program_scopes() if known is None else frozenset(known)
     devices = {k: v for k, v in ops_by_device.items() if v}
     loop = loop_spans(lines)
     bounds = window_bounds(loop, window_s)
@@ -204,7 +260,7 @@ def reduce_spans(ops_by_device: Dict[str, List[Op]], lines: List[List[Span]],
     pieces, off_step = [], 0.0
     n = len(devices)
     for ops in devices.values():
-        busy = busy_intervals([(o[0], o[1], o[2]) for o in ops])
+        busy = busy_intervals(ops)
         for a, b in idle_intervals(busy, *bounds):
             for label, g in seg.label(a, b):
                 pieces.append((label, g))
@@ -213,8 +269,10 @@ def reduce_spans(ops_by_device: Dict[str, List[Op]], lines: List[List[Span]],
         for name, s, d, path in ops:
             if name.split(".")[0] in CONTAINERS:
                 continue      # their operations count on their own
-            op_scope[name] = scope_of(path)
-            scope_by[op_scope[name]] += d / 1e9 / n
+            on = scopes_on(path, known) or [NO_SCOPE]
+            op_scope[name] = on[-1]
+            for scope in on:
+                scope_by[scope] += d / 1e9 / n
             span_by[seg.at(s)] += d / 1e9 / n
             per_op[name] += d / 1e9 / n
     return {"window_s": window_s,
@@ -223,10 +281,35 @@ def reduce_spans(ops_by_device: Dict[str, List[Op]], lines: List[List[Span]],
             "device_by_scope": dict(scope_by),
             "device_by_span": dict(span_by),
             "device_outside_step_run_s": off_step,
-            "device_ops": [[name, t, op_scope[name]] for name, t in
+            "device_ops": [[f"{op_scope[name]}/{name}", t] for name, t in
                            sorted(per_op.items(), key=lambda kv: -kv[1])[:top]],
             "idle_gaps": [[label, g] for label, g in
                           sorted(pieces, key=lambda p: -p[1])[:top]]}
+
+
+def reduce_trace(xplane_path: str, op_names: Dict[str, Dict[str, str]],
+                 window_s: float) -> Optional[dict]:
+    """``bench.trace.reduce``'s busy time and idle share of a traced window,
+    and ``reduce_spans`` over it where the trace holds the loop's spans.
+    None when the trace holds no device operation."""
+    ops = trace_mod.device_ops(xplane_path)
+    out = trace_mod.reduce(ops, window_s)
+    if out is not None:
+        out.update(reduce_spans(with_op_names(ops, op_names),
+                                host_spans(xplane_path), window_s) or {})
+    return out
+
+
+def scope_ms(run, scope: str) -> Optional[float]:
+    """Milliseconds of device time a window step under ``scope``, from the
+    run's traced window; None where no operation ran under it. Raises where
+    ``scope`` is no scope the program opens, so that a reader whose scope
+    the program does not name fails rather than reads nothing."""
+    if scope not in program_scopes():
+        raise KeyError(f"{scope!r} is not among the program's named scopes "
+                       f"{sorted(program_scopes())}")
+    t = (run.trace or {}).get("device_by_scope", {}).get(scope)
+    return None if t is None or not run.step_s else 1000.0 * t / len(run.step_s)
 
 
 # ---------------------------------------------------------------------------

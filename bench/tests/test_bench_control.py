@@ -8,7 +8,7 @@ import os
 
 import pytest
 
-import bench_tiny  # noqa: F401
+import bench_tiny
 
 from bench import control
 
@@ -19,7 +19,7 @@ def _cfg(name):
         return json.load(f)
 
 
-@pytest.mark.parametrize("name", ["tiny-lm", "tiny-mamba", "tiny-hybrid"])
+@pytest.mark.parametrize("name", bench_tiny.config_names())
 def test_control_and_faults_fail_a_limit(name):
     cfg = _cfg(name)
     readings = control.readings(cfg, bench_tiny.SEED,

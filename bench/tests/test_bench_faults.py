@@ -18,7 +18,7 @@ def _broken_step(monkeypatch, change):
 
 
 def test_sound_run_is_correct():
-    r = bench_tiny.run("lm.steady")
+    r = bench_tiny.run("tiny-lm.steady")
     assert r["correct"], r["checks"]
     assert r["metrics"]["train_tokens_per_s"]["value"] > 0
     assert list(r)[-1] == "checks"
@@ -27,7 +27,7 @@ def test_sound_run_is_correct():
 
 def test_sound_hybrid_run_is_correct():
     # a period of two kinds of layer, made only of the shipped parts
-    r = bench_tiny.run("hybrid.steady")
+    r = bench_tiny.run("tiny-hybrid.steady")
     assert r["correct"], r["checks"]
     assert r["metrics"]["train_tokens_per_s"]["value"] > 0
     assert {"gnorm_gap", "grad_leaf_gap", "change1_leaf_gap"} <= set(r["checks"])
@@ -38,7 +38,7 @@ def test_half_the_batch_left_out_is_not_correct(monkeypatch):
         b = batch["tokens"].shape[1] // 2
         return step(state, {k: v[:, :b] for k, v in batch.items()})
     _broken_step(monkeypatch, half)
-    r = bench_tiny.run("lm.steady")
+    r = bench_tiny.run("tiny-lm.steady")
     assert not r["correct"]
     assert r["checks"]["gnorm_gap"]["value"] > r["checks"]["gnorm_gap"]["limit"]
 
@@ -50,7 +50,7 @@ def test_parameters_left_unchanged_is_not_correct(monkeypatch):
         new, metrics = step(state, batch)
         return dict(new, params=state["params"]), metrics
     _broken_step(monkeypatch, frozen)
-    r = bench_tiny.run("lm.steady")
+    r = bench_tiny.run("tiny-lm.steady")
     assert not r["correct"]
     assert r["checks"]["state_mismatches"]["value"] > 0
     c = r["checks"]["change1_leaf_gap"]

@@ -15,14 +15,16 @@ def test_token_altered_at_the_source_is_not_correct(monkeypatch):
                 shard["tokens"][7] = (shard["tokens"][7] + 1) % self.vocab
         return out
     monkeypatch.setattr(pipeline_mod.SyntheticCorpus, "effect", altered)
-    r = bench_tiny.run("mamba.steady")
+    r = bench_tiny.run("tiny-mamba.steady")
     assert not r["correct"]
     assert r["checks"]["feed_mismatches"]["value"] == 1
 
 
 def test_mamba_cell_traced_on_cpu_reads_only_host_metrics():
-    r = bench_tiny.run("mamba.steady", trace=True)
+    r = bench_tiny.run("tiny-mamba.steady", trace=True)
     assert r["correct"], r["checks"]
     # no device plane on the CPU: the device readers return nothing
-    assert "device_idle_share" not in r["metrics"]
+    for name in ("device_idle_share", "mixer_device_ms", "ffn_device_ms",
+                 "optimizer_device_ms"):
+        assert name not in r["metrics"]
     assert "ckpt_save_s" in r["metrics"] and "feed_wait_ms" in r["metrics"]

@@ -9,7 +9,7 @@ import types
 import numpy as np
 import pytest
 
-import bench_tiny  # noqa: F401  (puts the checkout on sys.path)
+import bench_tiny  # puts the checkout on sys.path
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,7 @@ def _cfg(name):
         return json.load(f)
 
 
-@pytest.mark.parametrize("name", ["tiny-lm", "tiny-mamba", "tiny-hybrid"])
+@pytest.mark.parametrize("name", bench_tiny.config_names())
 def test_seeded_weights_are_the_programs(name):
     from repro.models import model as M
     cfg = _cfg(name)
@@ -59,7 +59,7 @@ def test_corpus_batches_are_the_feeds():
             R.corpus_batch(SEED, step, vocab, seq, bs), want)
 
 
-@pytest.mark.parametrize("name", ["tiny-lm", "tiny-mamba", "tiny-hybrid"])
+@pytest.mark.parametrize("name", bench_tiny.config_names())
 def test_float32_step_matches_the_program(name):
     from repro.models import model as M
     from repro.training.loss import loss_fn

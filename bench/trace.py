@@ -2,38 +2,60 @@
 
 The trace is JAX's ``.xplane.pb``. A device plane is named ``/device:<kind>:<n>``
 (TPU and GPU alike); its line ``XLA Ops`` holds one event per operation that
-ran on that device, with a start and a duration in nanoseconds. Busy time is
-the union of those intervals; the idle share is one minus busy over the
-window's length on the host clock.
+ran on that device, with a start and a duration in nanoseconds, and its line
+``XLA Modules`` one event per run of a program. Busy time is the union of
+the operations' intervals; the idle share is one minus busy over the
+window's length on the host clock. The top operations and the idle gaps,
+named by scope and by loop span, are ``bench/spans.py``'s.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
-from collections import defaultdict
+import re
 from typing import Dict, List, Optional, Tuple
 
 OPS_LINE = "XLA Ops"
-# operations that contain others on the same line: they count towards busy
-# time, but their own totals would hide the operations inside them
-CONTAINERS = ("while", "conditional", "call")
+MODULES_LINE = "XLA Modules"
 Interval = Tuple[float, float]          # start_ns, end_ns
 
 
-def device_ops(xplane_path: str) -> Dict[str, List[Tuple[str, float, float]]]:
-    """{device plane name: [(op name, start_ns, duration_ns), ...]}."""
+def device_ops(xplane_path: str
+               ) -> Dict[str, List[Tuple[str, float, float, str]]]:
+    """{device plane name: [(op name, start_ns, duration_ns, module), ...]}:
+    ``module`` is the program the operation ran in, the ``XLA Modules``
+    event that holds the operation's start (``module_name``), or "" where
+    none does."""
     import jax
     pd = jax.profiler.ProfileData.from_file(xplane_path)
-    out: Dict[str, List[Tuple[str, float, float]]] = {}
+    out: Dict[str, List[Tuple[str, float, float, str]]] = {}
     for plane in pd.planes:
         if not plane.name.startswith("/device:"):
             continue
-        for line in plane.lines:
-            if line.name == OPS_LINE:
-                out[plane.name] = [(op_name(e.name), float(e.start_ns),
-                                    float(e.duration_ns))
-                                   for e in line.events]
+        lines = {line.name: line for line in plane.lines}
+        if OPS_LINE not in lines:
+            continue
+        modules = sorted((float(e.start_ns), float(e.end_ns),
+                          module_name(e.name))
+                         for e in (lines[MODULES_LINE].events
+                                   if MODULES_LINE in lines else ()))
+        starts = [s for s, _, _ in modules]
+        ops = []
+        for e in lines[OPS_LINE].events:
+            s = float(e.start_ns)
+            i = bisect.bisect_right(starts, s) - 1
+            held = i >= 0 and s < modules[i][1]
+            ops.append((op_name(e.name), s, float(e.duration_ns),
+                        modules[i][2] if held else ""))
+        out[plane.name] = ops
     return out
+
+
+def module_name(event: str) -> str:
+    """``jit_train_step(42)`` -> ``jit_train_step``: the program's name as
+    its compiled text gives it, without the run's program id."""
+    return re.sub(r"\(\d+\)$", "", event.strip())
 
 
 def op_name(hlo: str) -> str:
@@ -47,9 +69,10 @@ def find_xplane(log_dir: str) -> Optional[str]:
     return hits[-1] if hits else None
 
 
-def busy_intervals(ops: List[Tuple[str, float, float]]) -> List[Interval]:
-    """The union of the operations' intervals, sorted and disjoint."""
-    spans = sorted((s, s + d) for _, s, d in ops if d > 0)
+def busy_intervals(ops: List[tuple]) -> List[Interval]:
+    """The union of the intervals of operations ``(name, start_ns,
+    duration_ns, ...)``, sorted and disjoint."""
+    spans = sorted((op[1], op[1] + op[2]) for op in ops if op[2] > 0)
     merged: List[Interval] = []
     for s, e in spans:
         if merged and s <= merged[-1][1]:
@@ -60,31 +83,14 @@ def busy_intervals(ops: List[Tuple[str, float, float]]) -> List[Interval]:
     return merged
 
 
-def reduce(ops_by_device: Dict[str, List[Tuple[str, float, float]]],
-           window_s: float, top: int = 10) -> Optional[dict]:
-    """busy_s (mean over devices), idle share, the operations that took most
-    device time and the longest gaps between busy intervals. None when the
-    trace holds no device operation."""
+def reduce(ops_by_device: Dict[str, List[tuple]],
+           window_s: float) -> Optional[dict]:
+    """busy_s (mean over devices), the window's length and the idle share.
+    None when the trace holds no device operation."""
     devices = {k: v for k, v in ops_by_device.items() if v}
     if not devices or window_s <= 0:
         return None
-    busy, per_op, gaps = [], defaultdict(float), []
-    for ops in devices.values():
-        iv = busy_intervals(ops)
-        busy.append(sum(e - s for s, e in iv) / 1e9)
-        for name, _, d in ops:
-            if name.split(".")[0] not in CONTAINERS:
-                per_op[name] += d / 1e9
-        gaps += [("host", (b[0] - a[1]) / 1e9) for a, b in zip(iv, iv[1:])]
-        span = (iv[-1][1] - iv[0][0]) / 1e9
-        # the window outside its first..last operation: the wait for the
-        # first batch and the final checkpoint save lie there
-        gaps.append(("host, before the first or after the last device op",
-                     max(window_s - span, 0.0)))
-    busy_s = sum(busy) / len(busy)
-    ops_top = sorted(per_op.items(), key=lambda kv: -kv[1])[:top]
-    gaps_top = sorted(gaps, key=lambda g: -g[1])[:top]
+    busy_s = sum(sum(e - s for s, e in busy_intervals(ops)) / 1e9
+                 for ops in devices.values()) / len(devices)
     return {"busy_s": busy_s, "window_s": window_s,
-            "idle_share": 1.0 - busy_s / window_s,
-            "device_ops": [[n, s / len(devices)] for n, s in ops_top],
-            "idle_gaps": [[label, g] for label, g in gaps_top]}
+            "idle_share": 1.0 - busy_s / window_s}
