@@ -270,6 +270,12 @@ class span:
         self._t0 = time.perf_counter()
         return self
 
+    def annotate(self, **args) -> None:
+        """Adds ``args`` to the open span's stats in the profiler's trace,
+        for values known only once its work has run."""
+        if self._note is not None:
+            self._note.set_metadata(**args)
+
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
         if self._note is not None:

@@ -26,8 +26,9 @@ def interpret_mode() -> bool:
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
-                    block_q: int = 512, block_k: int = 512):
-    """q,k,v: [B,S,H,D]; kv heads must be pre-expanded to H (GQA repeat)."""
+                    block_q: int = _fa.BLOCK_Q, block_k: int = _fa.BLOCK_K):
+    """q [B,S,H,D]; k,v [B,Sk,KV,D], H a multiple of KV (GQA). Has a
+    backward."""
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, block_q=block_q,
                                block_k=block_k, interpret=interpret_mode())

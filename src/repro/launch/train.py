@@ -38,6 +38,7 @@ from repro.core.metrics import span
 from repro.data import build_data_pipeline
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.presets import ONE_CHIP_TRAIN, REDUCED_TRAIN
+from repro.models import layers as L
 from repro.models import model as M
 from repro.training.optimizer import OptHParams
 from repro.training.step import init_train_state, make_train_step
@@ -78,7 +79,10 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
     checkpoint, ``ckpt.save`` and ``feed.ack`` (``core/metrics.span``;
     docs/metrics.md). ``timings`` holds the lengths of ``step.compile``
     (``compile_s``), ``step.run`` (``step_s``), ``ckpt.save`` (``save_s``)
-    and of each ``ckpt.restore`` that found a checkpoint (``restore_s``)."""
+    and of each ``ckpt.restore`` that found a checkpoint (``restore_s``),
+    and ``attn_paths``: the step's attention call sites by the path each
+    took, ``{"kernel": n, "xla": m}`` (also the ``step.compile`` span's
+    ``attn_kernel`` and ``attn_xla``)."""
     ts = REDUCED_TRAIN if use_reduced else ONE_CHIP_TRAIN
     seq_len = seq_len or ts.seq_len
     batch_size = batch_size or ts.batch_size
@@ -114,10 +118,14 @@ def run_training(*, arch: str = "internlm2-1.8b", use_reduced: bool = True,
     state = load_state()
     tok_spec = jax.ShapeDtypeStruct((1, batch_size, seq_len), jnp.int32)
     with span("step.compile") as compile_:
-        train_step = jax.jit(make_train_step(cfg, hp, rt),
-                             donate_argnums=0).lower(
-            state, {"tokens": tok_spec, "labels": tok_spec}).compile()
+        with L.tally_attn_paths() as attn_paths:
+            lowered = jax.jit(make_train_step(cfg, hp, rt),
+                              donate_argnums=0).lower(
+                state, {"tokens": tok_spec, "labels": tok_spec})
+        compile_.annotate(**{f"attn_{k}": n for k, n in attn_paths.items()})
+        train_step = lowered.compile()
     timings["compile_s"] = compile_.seconds
+    timings["attn_paths"] = attn_paths
 
     # ---- data pipeline (LOG.io-protected) --------------------------------
     pipeline, feed_id = build_data_pipeline(

@@ -5,13 +5,14 @@ is a pytree of tuples of logical axis names (mapped to mesh axes by
 ``repro.parallel.sharding``). Every ``apply_*`` is shape-polymorphic and used
 for train/prefill (full-sequence) and decode (single-token + cache) paths.
 
-Attention uses a *query-chunked* XLA path by default (memory-safe for 32k
-prefill without materialising the full S x S score matrix); on TPU the Pallas
-``flash_attention`` kernel from ``repro.kernels`` can be selected via
-``attn_impl="pallas"``.
+Attention runs the Pallas ``flash_attention`` kernel from ``repro.kernels``
+(forward and backward) where it compiles and fits, and otherwise a
+*query-chunked* XLA path (memory-safe for 32k prefill without materialising
+the full S x S score matrix); ``attn_impl`` pins either (``_attn_path``).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Any, Dict, Optional, Tuple
@@ -142,18 +143,50 @@ def _attn_mask(q_pos, k_pos, window: Optional[int]):
     return m
 
 
+# attention call sites traced by the path they took (``tally_attn_paths``)
+_ATTN_PATHS: dict | None = None
+
+
+@contextlib.contextmanager
+def tally_attn_paths():
+    """Yields ``{"kernel": n, "xla": m}``: the attention call sites traced
+    inside the block, by the path ``apply_attention`` chose for each."""
+    global _ATTN_PATHS
+    prev, _ATTN_PATHS = _ATTN_PATHS, {"kernel": 0, "xla": 0}
+    try:
+        yield _ATTN_PATHS
+    finally:
+        _ATTN_PATHS = prev
+
+
+def _attn_path(attn_impl: str, S: int, dh: int, self_attn: bool) -> str:
+    """"kernel" or "xla". ``"auto"`` takes the kernel where it compiles (not
+    the Pallas interpreter), where no sharding context is set (GSPMD would
+    replicate an unsharded ``pallas_call``), for self-attention whose length
+    and head width its blocks tile; both paths compute one function."""
+    if attn_impl in ("pallas", "xla"):
+        return "kernel" if attn_impl == "pallas" else "xla"
+    assert attn_impl == "auto", attn_impl
+    from repro.kernels import flash_attention as fa, ops as kops
+    ok = (not kops.interpret_mode() and _SHARD_CTX is None and self_attn
+          and fa.fits(S, dh))
+    return "kernel" if ok else "xla"
+
+
 def apply_attention(p: Params, x: jax.Array, spec: AttnSpec, cfg: ArchConfig,
                     positions: jax.Array, *, kv_override: Optional[Tuple] = None,
                     kv_positions: Optional[jax.Array] = None,
                     causal: bool = True, q_chunk: int = 1024,
-                    attn_impl: str = "xla") -> jax.Array:
+                    attn_impl: str = "auto") -> jax.Array:
     """Full-sequence attention (train / prefill / encoder / cross).
 
     x: [B, S, d].  kv_override: (k_src, v_src) already projected (cross-attn
     passes encoder memory through wk/wv itself via this fn with x_kv).
+    The kernel masks by sequence index: it assumes positions 0..S-1, which
+    is what ``forward`` and the encoder pass.
     """
     B, S, _ = x.shape
-    h, kv, dh = cfg.eff_heads, cfg.n_kv_heads, cfg.d_head
+    h, dh = cfg.eff_heads, cfg.d_head
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     if kv_override is None:
         k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
@@ -169,20 +202,36 @@ def apply_attention(p: Params, x: jax.Array, spec: AttnSpec, cfg: ArchConfig,
     if causal or kv_override is None:   # self-attn gets RoPE; cross does not
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, k_pos, cfg.rope_theta)
+    path = _attn_path(attn_impl, S, dh, kv_override is None)
+    if _ATTN_PATHS is not None:
+        _ATTN_PATHS[path] += 1
+    if path == "kernel":   # GQA inside the kernel: k, v stay at kv heads
+        from repro.kernels import ops as kops
+        out = kops.flash_attention(q, k, v, causal=causal,
+                                   window=spec.window, softcap=spec.softcap)
+    else:
+        out = _xla_attn(q, k, v, spec, positions, k_pos, causal, q_chunk)
+    if h != cfg.n_heads:   # zero TP-padded heads (blocks grads into wo pad)
+        out = out * (jnp.arange(h) < cfg.n_heads)[None, None, :, None
+                                                  ].astype(out.dtype)
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+
+
+def _xla_attn(q, k, v, spec: AttnSpec, positions, k_pos, causal: bool,
+              q_chunk: int) -> jax.Array:
+    """The query-chunked XLA path: [B, S, h, dh] out."""
+    B, S, h, dh = q.shape
+    Sk = k.shape[1]
     # GQA via repeat: kv heads -> q heads BEFORE the score einsum. The repeat
     # keeps the head dim shardable over "model" (a reshape h->(kv,groups)
     # would break GSPMD head sharding and replicate attention compute).
-    groups = h // kv
-    Sk = k.shape[1]
+    groups = h // k.shape[2]
     if groups > 1:
         k = jnp.repeat(k, groups, axis=2)
         v = jnp.repeat(v, groups, axis=2)
     q = _cs(q, "dp", None, "tp", None)
     k = _cs(k, "dp", None, "tp", None)
     v = _cs(v, "dp", None, "tp", None)
-
-    if attn_impl == "pallas":
-        return _pallas_attn(p, q, k, v, spec, positions, k_pos, causal)
 
     scale = 1.0 / math.sqrt(dh)
     n_chunks = max(1, S // q_chunk) if S % q_chunk == 0 else 1
@@ -209,20 +258,8 @@ def apply_attention(p: Params, x: jax.Array, spec: AttnSpec, cfg: ArchConfig,
         return jnp.einsum("bhqs,bshd->bqhd", pr.astype(v.dtype), v)
 
     out = lax.map(one_chunk, (jnp.moveaxis(qs, 1, 0), jnp.moveaxis(qpos, 1, 0)))
-    out = _cs(jnp.moveaxis(out, 0, 1).reshape(B, S, h, dh),
-              "dp", None, "tp", None)
-    if h != cfg.n_heads:   # zero TP-padded heads (blocks grads into wo pad)
-        out = out * (jnp.arange(h) < cfg.n_heads)[None, None, :, None
-                                                  ].astype(out.dtype)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-
-
-def _pallas_attn(p, q, k, v, spec, q_pos, k_pos, causal):
-    from repro.kernels import ops as kops
-    B, S, h, dh = q.shape
-    out = kops.flash_attention(q, k, v, causal=causal, window=spec.window,
-                               softcap=spec.softcap)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    return _cs(jnp.moveaxis(out, 0, 1).reshape(B, S, h, dh),
+               "dp", None, "tp", None)
 
 
 def apply_attention_decode(p: Params, x: jax.Array, spec: AttnSpec,

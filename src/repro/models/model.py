@@ -31,7 +31,7 @@ Params = Dict[str, Any]
 @dataclasses.dataclass(frozen=True)
 class Runtime:
     """Static runtime knobs (hashable; passed as static arg to jit)."""
-    attn_impl: str = "xla"        # "xla" | "pallas"
+    attn_impl: str = "auto"       # "auto" | "xla" | "pallas" (layers._attn_path)
     scan_impl: str = "chunked"    # mamba scan: "chunked" | "assoc" | "pallas"
     remat: str = "block"          # "none" | "block" | "full"
     q_chunk: int = 1024
@@ -244,7 +244,8 @@ def _encode(params, frames, cfg, rt):
     def enc_layer(x, lp):
         h = L.rms_norm(x, lp["norm1"], cfg.norm_eps)
         mix = L.apply_attention(lp["attn"], h, enc_spec.attn, cfg, positions,
-                                causal=False, q_chunk=rt.q_chunk)
+                                causal=False, q_chunk=rt.q_chunk,
+                                attn_impl=rt.attn_impl)
         x = x + mix
         h = L.rms_norm(x, lp["norm2"], cfg.norm_eps)
         x = x + L.apply_mlp(lp["mlp"], h, cfg.act)

@@ -144,6 +144,19 @@ def test_write_span_carries_the_leaf_bytes(traced):
     assert write["bytes"] > 0
 
 
+def test_compile_span_carries_the_attention_paths(traced):
+    """``timings["attn_paths"]`` and the ``step.compile`` span's arguments
+    give the step's attention call sites by path: on the CPU the tiny
+    attention model takes the XLA path."""
+    out, xplane = traced
+    pd = jax.profiler.ProfileData.from_file(xplane)
+    (compile_,) = [dict(e.stats) for plane in pd.planes
+                   if plane.name.startswith("/host:") for line in plane.lines
+                   for e in line.events if e.name == "step.compile"]
+    assert out["timings"]["attn_paths"] == {"kernel": 0, "xla": 1}
+    assert (compile_["attn_kernel"], compile_["attn_xla"]) == (0, 1)
+
+
 def test_log_commits_are_spans_on_the_feed_and_the_ack(traced_run):
     _, (loop, every) = traced_run
     assert any(n == "log.commit" for n, _, _ in every)
@@ -177,6 +190,7 @@ def test_every_span_name_is_documented(traced_run):
 def test_step_carries_the_named_scopes(arch):
     from repro.configs import get_config, reduced
     from repro.launch.presets import ONE_CHIP_TRAIN as ts
+    from repro.models import layers as L
     from repro.models import model as M
     from repro.training.optimizer import OptHParams
     from repro.training.step import init_train_state, make_train_step
@@ -188,8 +202,12 @@ def test_step_carries_the_named_scopes(arch):
     state = jax.eval_shape(lambda: init_train_state(
         jax.random.PRNGKey(0), cfg, hp, dtype=jnp.bfloat16))
     tok = jax.ShapeDtypeStruct((1, 2, 8), jnp.int32)
-    text = jax.jit(make_train_step(cfg, hp, rt)).lower(
-        state, {"tokens": tok, "labels": tok}).as_text(debug_info=True)
+    with L.tally_attn_paths() as attn_paths:
+        text = jax.jit(make_train_step(cfg, hp, rt)).lower(
+            state, {"tokens": tok, "labels": tok}).as_text(debug_info=True)
+    # one attention call site (the layer scan's body), on the XLA path here
+    assert attn_paths == ({"kernel": 0, "xla": 1} if arch.startswith(
+        "internlm") else {"kernel": 0, "xla": 0})
     paths = set(re.findall(r'loc\("([^"]*)"', text))
     want = {"embed", "blocks", "norm", "mixer", "head", "grad_accum",
             "optimizer"} | ({"ffn"} if arch.startswith("internlm") else set())

@@ -75,3 +75,54 @@ def test_selective_scan_compiles_for_v5e(one_chip):
     _compile(lambda a, b: _ss.selective_scan(a, b, chunk=256,
                                              interpret=False),
              one_chip, ab, ab)
+
+
+def test_flash_attention_backward_compiles_for_v5e(one_chip):
+    """The kernel's forward and ``custom_vjp`` backward at one internlm2
+    layer: B 2, 16 query heads on 8 kv heads of 128, 4096 tokens, causal."""
+    cfg = get_config("internlm2-1.8b")
+    q = ((2, 4096, cfg.n_heads, cfg.d_head), jnp.bfloat16)
+    kv = ((2, 4096, cfg.n_kv_heads, cfg.d_head), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: _fa.flash_attention(
+            q, k, v, interpret=False), q, k, v)
+        return (o,) + vjp(do)
+
+    text = _compile(fwd_bwd, one_chip, q, kv, kv, q).as_text()
+    for name in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert name in text, name
+
+
+def test_train_step_kernels_lie_under_mixer_for_v5e(one_chip, monkeypatch):
+    """A tiny attention model's train step compiled for the chip: each of
+    the kernel's custom calls (forward, its rematerialised copy, dK/dV, dQ)
+    carries the ``mixer`` scope on its ``op_name``, which is how the
+    benchmark's device time by scope finds it."""
+    import re
+
+    from repro.configs import reduced
+    from repro.kernels import ops
+    from repro.models import model as M
+    from repro.training.optimizer import OptHParams
+    from repro.training.step import init_train_state, make_train_step
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    cfg = reduced(get_config("internlm2-1.8b"), d_model=256, n_layers=1,
+                  vocab=256, d_ff=256, n_heads=2)
+    hp = OptHParams()
+    state = jax.eval_shape(lambda: init_train_state(
+        jax.random.PRNGKey(0), cfg, hp, dtype=jnp.bfloat16))
+    state = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), state)
+    tok = jax.ShapeDtypeStruct((1, 1, 256), jnp.int32, sharding=one_chip)
+    rt = M.Runtime(remat="full")
+    text = jax.jit(make_train_step(cfg, hp, rt)).lower(
+        state, {"tokens": tok, "labels": tok}).compile().as_text()
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines() if "tpu_custom_call" in line
+             and "op_name=" in line]
+    kernels = [p.split("/") for p in calls if "pallas_call" in p]
+    assert sorted({p[p.index("pallas_call") - 1] for p in kernels}) == [
+        "flash_dkv", "flash_dq", "flash_fwd"], calls
+    for p in kernels:
+        assert "mixer" in p, "/".join(p)
