@@ -7,8 +7,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   * lineage_fig10 — lineage-capture overhead vs plain LOG.io (<1.5% claim).
   * process — thread vs process execution mode + recovery latency
     (``benchmarks/process_mode.py``).
-  * roofline/* — per (arch x shape) dry-run step-time lower bound (us) and
-    dominant roofline term (EXPERIMENTS.md §Roofline reads the same data).
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--full] [--repeats N]
                                   [--only uc1,lineage] [--json FILE]
@@ -29,7 +27,7 @@ def main():
     ap.add_argument("--repeats", type=int, default=None)
     ap.add_argument("--only", default=None,
                     help="comma list: uc1,uc2,uc3,lineage,lineage_query,"
-                         "logstore,batching,controller,process,roofline")
+                         "logstore,batching,controller,process")
     ap.add_argument("--json", default=None,
                     help="also write the collected rows as JSON "
                          "(per-commit perf-trajectory artifact)")
@@ -39,7 +37,7 @@ def main():
 
     from benchmarks import (batching, controller, lineage_overhead,
                             lineage_query, logstore_throughput, process_mode,
-                            roofline, uc1, uc2, uc3)
+                            uc1, uc2, uc3)
     rows = []
     print("name,us_per_call,derived")
     for name, mod in (("uc1", uc1), ("uc2", uc2), ("uc3", uc3),
@@ -48,7 +46,7 @@ def main():
                       ("logstore", logstore_throughput),
                       ("batching", batching),
                       ("controller", controller),
-                      ("process", process_mode), ("roofline", roofline)):
+                      ("process", process_mode)):
         if only and name not in only:
             continue
         kwargs = {"repeats": repeats, "full": args.full}

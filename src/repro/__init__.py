@@ -1,3 +1,3 @@
-"""repro — LOG.io (unified rollback recovery + data lineage) on a multi-pod
-JAX training/serving framework. See README.md / DESIGN.md."""
+"""repro — LOG.io (unified rollback recovery + data lineage) feeding a JAX
+train step on one device. See README.md."""
 __version__ = "1.0.0"

@@ -5,8 +5,6 @@ import dataclasses
 
 from repro.configs.base import (ArchConfig, AttnSpec, LayerSpec, MambaSpec,
                                 MoESpec)
-from repro.configs.shapes import (ALL_SHAPES, DECODE_32K, LONG_500K,
-                                  PREFILL_32K, TRAIN_4K, ShapeSpec, shapes_for)
 
 from repro.configs.chameleon_34b import CONFIG as CHAMELEON_34B
 from repro.configs.starcoder2_7b import CONFIG as STARCODER2_7B

@@ -1,7 +1,5 @@
 """arctic-480b [moe] — 128 experts top-2 + parallel dense residual FFN
-[hf:Snowflake/snowflake-arctic-base; hf]. 128 experts shard 8-per-chip over
-the 16-way model axis (EP). Optimizer moments default to bf16 for this config
-(fits 256 x 16GB; see EXPERIMENTS.md §Dry-run)."""
+[hf:Snowflake/snowflake-arctic-base; hf]."""
 from repro.configs.base import ArchConfig, AttnSpec, LayerSpec, MoESpec
 
 CONFIG = ArchConfig(

@@ -40,7 +40,7 @@ class MoESpec:
     # s separate "experts" [E*s, d, f/s] so E*s can EP-shard a wider model
     # axis than E allows (grok: 8 experts * 2 = 16-way EP). Semantics are
     # identical: a token routed to expert e runs on shards e*s..e*s+s-1 and
-    # the halves sum in the combine scatter. §Perf lever.
+    # the halves sum in the combine scatter.
     expert_split: int = 1
 
 
@@ -111,7 +111,7 @@ class ArchConfig:
     def d_inner(self) -> int:
         return 0 if self.mamba is None else self.mamba.expand * self.d_model
 
-    # ---- analytic parameter / FLOP accounting (used by roofline) ----------
+    # ---- analytic parameter count ------------------------------------------
     def layer_kinds(self) -> Sequence[LayerSpec]:
         """Full per-layer spec list (block repeated)."""
         return list(self.block) * self.n_blocks
@@ -184,14 +184,3 @@ class ArchConfig:
         if self.vocab % tp != 0:
             kw["pad_vocab_to"] = up(self.vocab)
         return dataclasses.replace(self, **kw) if kw else self
-
-    def active_param_count(self) -> int:
-        """Active params per token (MoE: only top_k experts count)."""
-        if self.moe is None:
-            return self.param_count()
-        m = self.moe
-        full = self.param_count()
-        n_moe_layers = sum(1 for s in self.layer_kinds()
-                           if s.ffn in ("moe", "moe_dense"))
-        inactive = n_moe_layers * (m.n_experts - m.top_k) * 3 * self.d_model * self.d_ff
-        return full - inactive

@@ -1,7 +1,7 @@
 """gemma2-9b [dense] — alternating local(4096)/global attention, logit
 softcaps (attn 50, final 30) [arXiv:2408.00118; hf]. The repeating scan block
-is the (local, global) pair. Global layers are quadratic => long_500k skipped
-(see DESIGN.md §Arch-applicability)."""
+is the (local, global) pair. Global layers are quadratic, so the config is
+not ``subquadratic``."""
 from repro.configs.base import ArchConfig, AttnSpec, LayerSpec
 
 CONFIG = ArchConfig(

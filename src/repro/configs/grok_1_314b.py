@@ -1,7 +1,5 @@
 """grok-1-314b [moe] — 8 experts top-2, GQA(kv=8) [hf:xai-org/grok-1;
-unverified]. With only 8 experts on a 16-way model axis, the sharding rules
-use tensor-parallel-within-expert (shard expert d_ff) instead of EP — see
-parallel/sharding.py."""
+unverified]."""
 from repro.configs.base import ArchConfig, AttnSpec, LayerSpec, MoESpec
 
 CONFIG = ArchConfig(

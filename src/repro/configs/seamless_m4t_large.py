@@ -1,6 +1,6 @@
 """seamless-m4t-large-v2 [audio] — encoder-decoder, multimodal
-[arXiv:2308.11596; hf]. The speech/text frontend is a STUB: ``input_specs()``
-provides precomputed frame embeddings [B, S_src, d_model] for the encoder; the
+[arXiv:2308.11596; hf]. The speech/text frontend is a STUB: the batch
+carries precomputed frame embeddings [B, S_src, d_model] for the encoder; the
 24-layer decoder (self+cross attention) is the assigned backbone."""
 from repro.configs.base import ArchConfig, AttnSpec, LayerSpec
 

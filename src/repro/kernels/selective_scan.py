@@ -2,9 +2,10 @@
 
 h_t = a_t * h_{t-1} + b_t, elementwise over the flattened (d_inner x d_state)
 feature dim. The kernel keeps the per-chunk [chunk, block_f] tiles plus the
-carried state in VMEM; a_t/b_t never round-trip to HBM between timesteps —
-this is the memory-roofline fix for the falcon-mamba/jamba train cells
+carried state in VMEM; a_t/b_t never round-trip to HBM between timesteps
 (the XLA associative-scan path materialises [B,S,di,ds] f32 intermediates).
+It has no backward, so no train step takes it: ``chip_smoke.py`` and the
+tests check it against ``kernels/ref.py``.
 
 Grid: (B, F/block_f, S/chunk); the chunk axis is sequential ("arbitrary"),
 carrying h in a VMEM scratch.

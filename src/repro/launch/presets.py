@@ -1,54 +1,7 @@
-"""Per-architecture dry-run presets (dtypes, accumulation, strategy knobs;
-dryrun.py flags override them) and the settings ``run_training`` uses on
-the CPU and on one chip.
-"""
+"""Training settings: the reduced CPU path and full width on one chip."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
-
-
-@dataclasses.dataclass(frozen=True)
-class Preset:
-    moment_dtype: str = "float32"
-    grad_accum_dtype: str = "float32"
-    remat: str = "block"
-    fsdp: bool = True
-    ep: bool = True
-    # microbatch sequences per accumulation step; None => one seq per DP shard
-    microbatch: Optional[int] = None
-    q_chunk: int = 1024
-    # §Perf winners: pure-DP+FSDP training for small models (removes the
-    # per-token TP activation all-reduces; train shapes only) and
-    # expert-splitting so grok's 8 experts EP-shard the 16-way axis.
-    dp_only_train: bool = False
-    expert_split: int = 1
-
-
-# >=300B configs: bf16 moments + bf16 accumulation to fit 256 x 16GB HBM.
-_BIG = Preset(moment_dtype="bfloat16", grad_accum_dtype="bfloat16",
-              remat="full")
-
-PRESETS = {
-    # >=30B dense: full remat (checkpoint-dots pushed chameleon/qwen3 train
-    # past 16GB/chip at baseline)
-    "chameleon-34b": Preset(remat="full"),
-    "starcoder2-7b": Preset(dp_only_train=True, remat="full"),
-    "internlm2-1.8b": Preset(dp_only_train=True, remat="full"),
-    "qwen3-32b": Preset(remat="full"),
-    "gemma2-9b": Preset(),
-    "jamba-1.5-large-398b": _BIG,
-    "seamless-m4t-large-v2": Preset(dp_only_train=True, remat="full"),
-    # grok: 8 experts split 2-way => 16-way EP (2.2x collective win, §Perf)
-    "grok-1-314b": dataclasses.replace(_BIG, expert_split=2),
-    # 480B: blockwise-int8 AdamW moments (bf16 moments left 25.8GB/chip)
-    "arctic-480b": dataclasses.replace(_BIG, moment_dtype="int8"),
-    "falcon-mamba-7b": Preset(),
-}
-
-
-def preset_for(arch_name: str) -> Preset:
-    return PRESETS.get(arch_name, Preset())
 
 
 @dataclasses.dataclass(frozen=True)

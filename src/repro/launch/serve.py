@@ -3,9 +3,7 @@
 Reduced configs only, on one device (CPU or one chip):
     PYTHONPATH=src python -m repro.launch.serve --arch gemma2-9b \
         --requests 8 --tokens 16
-No full-width serving path exists yet. ``serve_step`` is also what
-``launch/dryrun.py`` lowers, on forced CPU devices, for the production
-mesh's decode cells; nothing has run it sharded on a chip.
+No full-width serving path exists yet.
 """
 from __future__ import annotations
 

@@ -1,9 +1,9 @@
 """Pure-JAX model layers shared by all 10 assigned architectures.
 
 Every ``init_*`` returns ``(params, logical_specs)`` where ``logical_specs``
-is a pytree of tuples of logical axis names (mapped to mesh axes by
-``repro.parallel.sharding``). Every ``apply_*`` is shape-polymorphic and used
-for train/prefill (full-sequence) and decode (single-token + cache) paths.
+is a pytree of tuples of logical axis names (no mesh maps them yet). Every
+``apply_*`` is shape-polymorphic and used for train/prefill (full-sequence)
+and decode (single-token + cache) paths.
 
 Attention runs the Pallas ``flash_attention`` kernel from ``repro.kernels``
 (forward and backward) where it compiles and fits, and otherwise a
@@ -346,7 +346,7 @@ def apply_mlp(p: Params, x: jax.Array, act: str) -> jax.Array:
 
 # ---------------------------------------------------------------------------
 # MoE — top-k routing with capacity, gather/scatter dispatch (no O(T·E·C)
-# one-hot einsums; see DESIGN.md).  TPU-idiomatic: sort-based slotting.
+# one-hot einsums).  TPU-idiomatic: sort-based slotting.
 # ---------------------------------------------------------------------------
 
 
@@ -540,16 +540,16 @@ def _mamba_pre(p: Params, x: jax.Array, cfg: ArchConfig,
 MAMBA_CHUNK = 256
 
 
-def apply_mamba(p: Params, x: jax.Array, cfg: ArchConfig,
-                scan_impl: str = "chunked") -> jax.Array:
+def apply_mamba(p: Params, x: jax.Array, cfg: ArchConfig) -> jax.Array:
     """Full-sequence selective scan. x: [B,S,d] -> [B,S,d].
 
-    Default path is CHUNKED+FUSED: a sequential lax.scan over
-    S/MAMBA_CHUNK chunks carrying the SSM state; the [*, chunk, di, ds]
-    expansion a_t=exp(dt A), b_t=dt*B*u AND the contraction y=h.C happen
-    INSIDE the chunk body, so no [B,S,di,ds] tensor ever exists — only
-    [B,chunk,di,ds] working sets (the same blocking the Pallas
-    selective_scan kernel keeps in VMEM). §Perf P5/P8.
+    Where S is a multiple of MAMBA_CHUNK above it, the scan is CHUNKED and
+    FUSED: a sequential lax.scan over S/MAMBA_CHUNK chunks carrying the SSM
+    state; the [*, chunk, di, ds] expansion a_t=exp(dt A), b_t=dt*B*u AND
+    the contraction y=h.C happen INSIDE the chunk body, so no [B,S,di,ds]
+    tensor ever exists — only [B,chunk,di,ds] working sets (the same
+    blocking the Pallas selective_scan kernel keeps in VMEM). Shorter or
+    ragged sequences take one associative scan over the whole sequence.
     """
     ms = cfg.mamba
     S = x.shape[1]
@@ -568,12 +568,7 @@ def apply_mamba(p: Params, x: jax.Array, cfg: ArchConfig,
             B_c.astype(jnp.float32)[:, :, None, :]
         return a, _cs(b, "dp", None, "tp", None)
 
-    if scan_impl == "pallas":
-        from repro.kernels import ops as kops
-        a, b = expand(u, dt, Bc)
-        h = kops.selective_scan(a, b)
-        y = jnp.einsum("bsin,bsn->bsi", h, Cc.astype(jnp.float32))
-    elif scan_impl == "chunked" and S > MAMBA_CHUNK and S % MAMBA_CHUNK == 0:
+    if S > MAMBA_CHUNK and S % MAMBA_CHUNK == 0:
         n = S // MAMBA_CHUNK
         Bsz, di, ds = x.shape[0], cfg.d_inner, ms.d_state
 

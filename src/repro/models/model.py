@@ -1,8 +1,8 @@
 """Model assembly: decoder LM (all archs) + encoder-decoder (seamless).
 
 Layers repeat as *blocks* (cfg.block pattern) stacked with ``lax.scan`` so the
-HLO contains one block body regardless of depth — critical for fast GSPMD
-compiles at 256/512 devices. Params for in-block position ``i`` live in
+HLO contains one block body regardless of depth, so compile time does not
+grow with depth. Params for in-block position ``i`` live in
 ``params["blocks"][i]`` with every leaf stacked over ``n_blocks`` on axis 0.
 
 Public API:
@@ -32,7 +32,6 @@ Params = Dict[str, Any]
 class Runtime:
     """Static runtime knobs (hashable; passed as static arg to jit)."""
     attn_impl: str = "auto"       # "auto" | "xla" | "pallas" (layers._attn_path)
-    scan_impl: str = "chunked"    # mamba scan: "chunked" | "assoc" | "pallas"
     remat: str = "block"          # "none" | "block" | "full"
     q_chunk: int = 1024
     aux_loss_weight: float = 0.01
@@ -157,7 +156,7 @@ def _apply_layer(p: Params, spec: LayerSpec, x, positions, cfg, rt: Runtime,
                                     q_chunk=rt.q_chunk,
                                     attn_impl=rt.attn_impl)
         else:
-            mix = L.apply_mamba(p["mamba"], h, cfg, scan_impl=rt.scan_impl)
+            mix = L.apply_mamba(p["mamba"], h, cfg)
     x = x + mix
     if memory is not None:
         with jax.named_scope("norm"):

@@ -33,8 +33,8 @@ def make_serve_step(cfg, rt: M.Runtime, temperature: float = 0.0):
 
 class SlotServer:
     """Minimal continuous-batching server: fixed B slots, per-slot position,
-    requests queue in when slots free up. Used by examples/serve_batched.py
-    (CPU, reduced configs) — the dry-run lowers serve_step itself."""
+    requests queue in when slots free up. Driven by ``repro.launch.serve``
+    (reduced configs, one device)."""
 
     def __init__(self, params, cfg, rt: M.Runtime, n_slots: int,
                  max_len: int, bos: int = 1):
@@ -48,6 +48,7 @@ class SlotServer:
         self.outputs: Dict[int, list] = {}
         self._step = jax.jit(make_serve_step(cfg, rt))
         self._next_req = 0
+        self._slot_req: Dict[int, int] = {}
 
     def submit(self, prompt_token: int) -> int:
         rid = self._next_req
@@ -57,8 +58,11 @@ class SlotServer:
                 self.active[s] = True
                 self.tokens = self.tokens.at[s].set(prompt_token)
                 self.pos = self.pos.at[s].set(0)
+                # attention masks stale rows by position, but a Mamba
+                # layer's conv/SSM state would carry the last request over
+                self.cache = jax.tree.map(lambda c: c.at[:, s].set(0),
+                                          self.cache)
                 self.outputs[rid] = []
-                self._slot_req = getattr(self, "_slot_req", {})
                 self._slot_req[s] = rid
                 return rid
         raise RuntimeError("no free slot")
@@ -75,7 +79,7 @@ class SlotServer:
                 self.outputs[rid].append(int(nxt[s]))
 
     def finish(self, rid: int):
-        for s, r in getattr(self, "_slot_req", {}).items():
+        for s, r in self._slot_req.items():
             if r == rid:
                 self.active[s] = False
         return self.outputs.pop(rid)

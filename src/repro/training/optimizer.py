@@ -1,9 +1,9 @@
 """Custom AdamW with configurable moment dtypes.
 
-``moment_dtype=bf16`` halves optimizer-state HBM (used for the >=300B MoE
-configs to fit 256 x 16GB); ``moment_dtype=int8`` enables the blockwise-
-quantized (bnb-style) moments implemented in ``quant.py`` — a beyond-paper
-memory-term optimization evaluated in EXPERIMENTS.md §Perf.
+``moment_dtype=bf16`` halves optimizer-state HBM; ``moment_dtype=int8``
+enables the blockwise-quantized (bnb-style) moments implemented in
+``quant.py``, which ``presets.ONE_CHIP_TRAIN`` needs to fit internlm2-1.8b
+on one chip.
 """
 from __future__ import annotations
 

@@ -9,8 +9,7 @@ no reshape-induced resharding/all-gather appears in the update (a flat
 re-sharding the flat<->param reshapes).
 
 Used for (a) int8 AdamW moments (memory-term lever: 1.25B/el vs 2B bf16 /
-4B f32) and (b) int8 gradient all-reduce with bounded error
-(collective-term lever). §Perf.
+4B f32) and (b) int8 gradient all-reduce with bounded error.
 """
 from __future__ import annotations
 

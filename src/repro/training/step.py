@@ -1,14 +1,13 @@
 """The SPMD train step: microbatch gradient accumulation + AdamW.
 
-``batch["tokens"]`` arrives pre-shaped ``[accum, mb, S]`` (see
-``launch/input_specs.py``) so the accumulation scan never reshapes a sharded
-dimension. Forward+backward run per microbatch inside the scan body, so the
-live activation set is one microbatch (remat policy per ``Runtime``).
+``batch["tokens"]`` arrives pre-shaped ``[accum, mb, S]`` so the
+accumulation scan never reshapes a sharded dimension. Forward+backward run
+per microbatch inside the scan body, so the live activation set is one
+microbatch (remat policy per ``Runtime``).
 
 Optional gradient compression (``compress_grads``) quantizes the accumulated
 gradient to int8 blockwise before the (XLA-inserted) data-axis reduction and
-dequantizes after, with an error-feedback buffer folded into the next step —
-the collective-term lever measured in §Perf.
+dequantizes after, with an error-feedback buffer folded into the next step.
 """
 from __future__ import annotations
 

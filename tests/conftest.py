@@ -1,8 +1,8 @@
 import os
 import sys
 
-# tests must see exactly ONE device (the dry-run forces 512 in its own
-# process only); make sure nothing leaks XLA_FLAGS into the test run
+# tests must see exactly ONE device: make sure no XLA_FLAGS (a forced host
+# device count, say) leak into the test run
 os.environ.pop("XLA_FLAGS", None)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
